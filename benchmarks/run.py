@@ -44,6 +44,8 @@ def main() -> None:
                     help="trajectory JSON to append to (tests point this "
                          "at a scratch file so real history stays clean)")
     args = ap.parse_args()
+    from repro.launch.cache import use_compile_cache
+    use_compile_cache()
 
     failures = []
     ran = []

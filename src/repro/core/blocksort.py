@@ -45,8 +45,11 @@ _DEFAULT_MIN_BLOCK = 512
 # VMEM cap counts every ref the merge kernel holds: each is (8, 2B) x 4B.
 # Key-only merge has 2 refs (in+out) -> 4 MiB at B=32Ki; every further array
 # in the tuple (payload or extra key lane) adds 2 refs, halving the cap at
-# each doubling: kv (4 refs) -> 4 MiB at B=16Ki. All leave headroom in a
-# 16 MiB VMEM core for double buffering.
+# each doubling: kv (4 refs) -> 4 MiB at B=16Ki. With double buffering and
+# one network stage's temporaries the TPU v5e compiler counts 16.6-17 MiB of
+# scoped VMEM at the cap, over its 16 MiB default scope; the merge kernel
+# therefore asks for ``merge_kernel.VMEM_LIMIT`` (32 MiB of the core's
+# 128 MiB).
 _MAX_BLOCK = 1 << 15
 _TARGET_BLOCKS = 16       # merge rounds = num_blocks; keep that small
 
@@ -87,28 +90,37 @@ def _pad_grid_rows(x):
     return jnp.concatenate([x, fill], axis=0), rows
 
 
-def _merge_rounds(xs, nb, block, interpret):
-    """nb alternating even/odd block-pair merge rounds over (rows, nb*block).
+def _merge_round(xs, nb, block, parity, interpret):
+    """One block-pair merge round of the given parity over (rows, nb*block).
 
     ``xs`` is a tuple of lane/payload arrays; untouched edge blocks (the
     first block on odd rounds, the last on rounds with a dangling block) are
     carried through by concatenation around the merged span."""
-    npad = nb * block
-    for r in range(nb):
-        parity = r % 2
-        npairs = (nb - parity) // 2
-        if npairs == 0:
-            continue
-        lo = parity * block
-        hi = lo + npairs * 2 * block
-        merged = merge_adjacent_lex_pallas(
-            *(a[:, lo:hi] for a in xs), block=block, interpret=interpret)
-        if lo == 0 and hi == npad:
-            xs = merged
-        else:
-            xs = tuple(
-                jnp.concatenate([a[:, :lo], m, a[:, hi:]], axis=1)
-                for a, m in zip(xs, merged))
+    npairs = (nb - parity) // 2
+    if npairs == 0:
+        return xs
+    lo = parity * block
+    hi = lo + npairs * 2 * block
+    merged = merge_adjacent_lex_pallas(
+        *(a[:, lo:hi] for a in xs), block=block, interpret=interpret)
+    if lo == 0 and hi == nb * block:
+        return merged
+    return tuple(jnp.concatenate([a[:, :lo], m, a[:, hi:]], axis=1)
+                 for a, m in zip(xs, merged))
+
+
+def _merge_rounds(xs, nb, block, interpret):
+    """nb alternating even/odd block-pair merge rounds over (rows, nb*block),
+    as a loop over (even, odd) round pairs: the program holds two merge
+    kernels whatever nb is, where an unrolled sequence held nb of them and
+    its compile time grew with the row width."""
+    def pair(_, xs):
+        xs = _merge_round(xs, nb, block, 0, interpret)
+        return _merge_round(xs, nb, block, 1, interpret)
+
+    xs = jax.lax.fori_loop(0, nb // 2, pair, tuple(xs))
+    if nb % 2:
+        xs = _merge_round(xs, nb, block, 0, interpret)
     return xs
 
 

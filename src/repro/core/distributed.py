@@ -896,6 +896,8 @@ def distributed_chunked_sort_lex(keys, mesh=None, axis: str = "data",
             else:
                 merged = merge_runs(sub_lanes, engine=merge_engine,
                                     cmp_runs=sub_cmps, supervisor=supervisor)
+            log.info("destination %d: %d row(s) merged on %s", d,
+                     incoming[d], sorted(merged[0].devices(), key=str))
             if clipped and incoming[d] > capacity:
                 log.warning("run exchange overflow: destination %d clipped "
                             "%d element(s) past capacity %d", d,

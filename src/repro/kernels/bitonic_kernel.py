@@ -21,7 +21,7 @@ import jax.numpy as jnp
 from jax import lax
 from jax.experimental import pallas as pl
 
-from .lex import lex_gt_lanes, select_lanes
+from .lex import lane_partners, lex_gt_lanes, select_lanes
 
 __all__ = [
     "bitonic_rows_lex_kernel",
@@ -34,38 +34,46 @@ __all__ = [
 def _stage(arrs, col, j, direction_asc):
     """Compare-exchange with partner col ^ j; ascending where mask True."""
     bit_unset = (col & j) == 0
-    # partner value: col+j for bit-unset lanes (roll left), col-j otherwise.
-    partners = [
-        jnp.where(bit_unset, jnp.roll(a, -j, axis=1), jnp.roll(a, j, axis=1))
-        for a in arrs
-    ]
+    # partner value: col+j for bit-unset lanes, col-j otherwise.
+    partners = lane_partners(arrs, bit_unset, j)
     # Full-tuple lex compare (trailing payload lanes are the tie-break):
     # keeps the all-sentinel padding tuple strictly maximal so it cannot
     # displace a real payload when a real key equals the sentinel
     # (long-distance swaps are not stable).
     gt = lex_gt_lanes(arrs, partners)
     lt = lex_gt_lanes(partners, arrs)
-    swap = jnp.where(direction_asc, jnp.where(bit_unset, gt, lt),
-                     jnp.where(bit_unset, lt, gt))
+    # the lane keeps the min iff its direction agrees with its side; boolean
+    # ops, since a select between boolean vectors has no TPU lowering
+    take_min = ~(direction_asc ^ bit_unset)
+    swap = (take_min & gt) | (~take_min & lt)
     return select_lanes(swap, partners, arrs)
 
 
-def _network(arrs):
-    ncols = arrs[0].shape[1]
-    col = lax.broadcasted_iota(jnp.int32, arrs[0].shape, 1)
-    for stage in range(1, int(math.log2(ncols)) + 1):
-        kk = 1 << stage
-        direction_asc = (col & kk) == 0
-        for sub in reversed(range(stage)):
-            arrs = _stage(arrs, col, 1 << sub, direction_asc)
-    return arrs
-
-
 def bitonic_rows_lex_kernel(*refs):
+    """The network runs in place on the output refs as two nested loops
+    (merge stage, then partner distance) with the distance a loop value, so
+    the kernel's code holds one compare-exchange, not log^2(cols) unrolled
+    copies of it — unrolled, an 8192-lane block took minutes to compile."""
     n = len(refs) // 2
-    out = _network(tuple(r[...] for r in refs[:n]))
-    for r, o in zip(refs[n:], out):
-        r[...] = o
+    outs = refs[n:]
+    for r, o in zip(refs[:n], outs):
+        o[...] = r[...]
+    ncols = outs[0].shape[1]
+    col = lax.broadcasted_iota(jnp.int32, outs[0].shape, 1)
+
+    def stage(s, carry):
+        direction_asc = (col & (1 << s)) == 0
+
+        def sub(t, carry):
+            j = 1 << (s - 1 - t)
+            arrs = _stage(tuple(o[...] for o in outs), col, j, direction_asc)
+            for o, a in zip(outs, arrs):
+                o[...] = a
+            return carry
+
+        return lax.fori_loop(0, s, sub, carry)
+
+    lax.fori_loop(1, int(math.log2(ncols)) + 1, stage, 0)
 
 
 def _row_block(rows: int) -> int:

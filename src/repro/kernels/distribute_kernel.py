@@ -38,6 +38,8 @@ import jax.numpy as jnp
 from jax import lax
 from jax.experimental import pallas as pl
 
+from .lex import lane_roll
+
 __all__ = ["distribute_rows_kernel", "distribute_rows_pallas"]
 
 
@@ -66,13 +68,29 @@ def distribute_rows_kernel(keys_ref, dest_ref, rank_ref, cnt_ref, *,
     # Stable rank: within-block exclusive prefix count of same-destination
     # words, offset by the running (pre-block) histogram carried in cnt_ref.
     running = cnt_ref[...]                               # (1, B_pad)
+    bucket = lax.broadcasted_iota(jnp.int32, running.shape, 1)
     rank = jnp.zeros_like(dest)
+    block_hist = jnp.zeros_like(running)
     for p in range(num_buckets):                         # static, <= 4*lanes+1
         m = (dest == p).astype(jnp.int32)
-        excl = jnp.cumsum(m, axis=1) - m
-        rank = jnp.where(m == 1, excl + running[0, p], rank)
-        cnt_ref[:, p] = running[:, p] + jnp.sum(m, axis=1)
+        incl = _prefix_sum_lanes(m)
+        base = running[:, p:p + 1]                       # (1, 1)
+        rank = jnp.where(m == 1, incl - 1 + base, rank)
+        block_hist = jnp.where(bucket == p, incl[:, col_block - 1:], block_hist)
+    cnt_ref[...] = running + block_hist
     rank_ref[...] = rank
+
+
+def _prefix_sum_lanes(m):
+    """Inclusive prefix sum along the lanes of a (1, C) int32 row:
+    ceil(log2 C) rotate-and-add steps (Hillis-Steele). ``cumsum`` has no
+    Pallas TPU lowering."""
+    col = lax.broadcasted_iota(jnp.int32, m.shape, 1)
+    step = 1
+    while step < m.shape[1]:
+        m = m + jnp.where(col >= step, lane_roll(m, step), 0)
+        step *= 2
+    return m
 
 
 @functools.partial(jax.jit, static_argnames=("n_valid", "num_buckets",

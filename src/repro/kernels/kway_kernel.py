@@ -18,17 +18,20 @@ authors' MPI follow-up). This module collapses the combine to one launch:
      ``merge_take_packed`` applied along the tree), so the ranks are exactly
      a permutation of ``[0, total)``. One ``searchsorted`` of
      each run's ranks over the block boundaries turns them into per-block
-     segment cursors — and unlike ``runmerge_kernel.py``, those cursors ride
-     into the kernel as the scalar-prefetch operand of a
-     ``PrefetchScalarGridSpec``: the split is consumed *in-kernel* from SMEM,
-     there is no host-side gather/scatter of the data lanes at all.
+     segment cursors, and those cursors ride into the kernel as SMEM blocks
+     of 128 columns of the (run, block) table (grid step k reads columns k
+     and k+1; the whole table would outgrow SMEM): the split is consumed
+     *in-kernel*, there is no host-side gather/scatter of the data lanes at
+     all.
   2. **2-slot double-buffered segment DMA**: each grid step starts the async
      copies for output block ``k+1`` into the alternate scratch slot before
      waiting on block ``k``'s, so the k segment fetches for the next block
      overlap the merge network of the current one and HBM latency hides
-     behind compute.
+     behind compute. A DMA may only start on a 128-lane tile boundary, so
+     each fetch is the ``block + 128``-lane window from the boundary below
+     its segment, rotated into place in VMEM (``lex.segment_window``).
   3. **Block-granularity loser tree**: the per-run cursor state lives in
-     SMEM (the prefetched starts matrix); selection runs as a pairwise
+     SMEM (the blocks of the starts table); selection runs as a pairwise
      elimination tree over the k resident VMEM segments — each round merges
      two block-sorted windows with ``merge_kernel._merge_network`` and
      keeps the low ``block`` (the "winners"), so after ceil(log2 k) rounds
@@ -64,11 +67,12 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from .keypack import merge_take_packed, packed_cmp_lanes
-from .lex import sentinel_for, to_order_bits
+from .lex import (LANE_TILE, concat_lanes, pad_run, segment_window,
+                  to_order_bits, window_start)
 from .merge_kernel import _merge_network
 
 __all__ = ["DEFAULT_KWAY_BLOCK", "kway_ranks", "merge_runs_kway_take",
-           "merge_runs_kway_pallas", "merge_kway_pallas"]
+           "kway_kernel_call", "merge_runs_kway_pallas", "merge_kway_pallas"]
 
 # one output tile per grid step; 2 slots x k segments of every lane in VMEM
 DEFAULT_KWAY_BLOCK = 256
@@ -136,15 +140,15 @@ def merge_runs_kway_take(runs, n_cmp=None, max_values=None):
     cmp_runs = _cmp_runs(runs, n_cmp, max_values)
     nc = len(cmp_runs[0])
     total = sum(r[0].shape[0] for r in runs)
-    keys = tuple(to_order_bits(jnp.concatenate([c[i] for c in cmp_runs]))
+    keys = tuple(to_order_bits(concat_lanes([c[i] for c in cmp_runs]))
                  for i in range(nc))
     src = jnp.arange(total, dtype=jnp.int32)
     perm = lax.sort(keys + (src,), num_keys=nc, is_stable=True)[-1]
-    return tuple(jnp.concatenate([r[i] for r in runs])[perm]
+    return tuple(concat_lanes([r[i] for r in runs])[perm]
                  for i in range(len(runs[0])))
 
 
-def _kway_kernel(starts_ref, *refs, n_arr, n_runs, block):
+def _kway_kernel(cur_ref, nxt_ref, *refs, n_arr, n_runs, block):
     in_refs = refs[:n_arr]
     out_refs = refs[n_arr:2 * n_arr]
     scr = refs[2 * n_arr:3 * n_arr]
@@ -152,16 +156,24 @@ def _kway_kernel(starts_ref, *refs, n_arr, n_runs, block):
     k = pl.program_id(0)
     nb = pl.num_programs(0)
 
-    # starts_ref[r, j] is the ABSOLUTE offset of run r's segment for output
-    # block j inside the flat (run || sentinel-pad) concatenation, so the
-    # segment count is the plain difference and every read stays in bounds.
-    def stage(blk, slot):
+    # cur_ref[r, k % 128] / nxt_ref[r, (k + 1) % 128] are the ABSOLUTE
+    # offsets of run r's segments for output blocks k and k+1 inside the
+    # flat (run || sentinel-pad) concatenation, so the segment count is the
+    # plain difference and every read stays in bounds. Each DMA fetches the
+    # lane-tile-aligned window that holds the segment (segment_window: a DMA
+    # may only start on a 128-lane tile boundary).
+    def copy(i, r, start, slot):
+        return pltpu.make_async_copy(
+            in_refs[i].at[:, pl.ds(window_start(start), block + LANE_TILE)],
+            scr[i].at[slot * n_runs + r], sem.at[slot, i, r])
+
+    cur = lax.rem(k, LANE_TILE)
+    nxt = lax.rem(k + 1, LANE_TILE)
+
+    def stage(starts_ref, col, slot):
         for i in range(n_arr):
             for r in range(n_runs):
-                pltpu.make_async_copy(
-                    in_refs[i].at[:, pl.ds(starts_ref[r, blk], block)],
-                    scr[i].at[pl.ds(slot * n_runs + r, 1), :],
-                    sem.at[slot, i, r]).start()
+                copy(i, r, starts_ref[r, col], slot).start()
 
     # 2-slot double buffer: block k+1's k segment DMAs start into the
     # alternate slot before this block's are awaited, so the fetches for the
@@ -170,28 +182,24 @@ def _kway_kernel(starts_ref, *refs, n_arr, n_runs, block):
 
     @pl.when(k == 0)
     def _():
-        stage(0, 0)
+        stage(cur_ref, cur, 0)
 
     @pl.when(k + 1 < nb)
     def _():
-        stage(k + 1, lax.rem(k + 1, 2))
+        stage(nxt_ref, nxt, lax.rem(k + 1, 2))
 
     for i in range(n_arr):
         for r in range(n_runs):
-            pltpu.make_async_copy(
-                in_refs[i].at[:, pl.ds(starts_ref[r, k], block)],
-                scr[i].at[pl.ds(slot * n_runs + r, 1), :],
-                sem.at[slot, i, r]).wait()
+            copy(i, r, cur_ref[r, cur], slot).wait()
 
     # Resident segments, tails masked to the lex-maximal sentinel tuple so
     # every window is sorted ascending and fills sink past real elements.
-    col = lax.broadcasted_iota(jnp.int32, (1, block), 1)
     segs = []
     for r in range(n_runs):
-        cnt = starts_ref[r, k + 1] - starts_ref[r, k]
+        start = cur_ref[r, cur]
+        cnt = nxt_ref[r, nxt] - start
         segs.append(tuple(
-            jnp.where(col < cnt, scr[i][pl.ds(slot * n_runs + r, 1), :],
-                      sentinel_for(scr[i].dtype))
+            segment_window(scr[i][slot * n_runs + r], start, cnt, block)
             for i in range(n_arr)))
 
     # Loser tree at block granularity: pairwise elimination rounds; each
@@ -213,6 +221,35 @@ def _kway_kernel(starts_ref, *refs, n_arr, n_runs, block):
         ref[...] = m
 
 
+def kway_kernel_call(starts, *flat, nblocks, block, interpret=False):
+    """The streaming kernel launch alone. ``starts``: (n_runs, C) int32,
+    column j the runs' segment starts for output block j (C >= nblocks + 1,
+    a multiple of 128); ``flat``: per lane, the (1, L) concatenation of the
+    runs, each followed by ``block + 128`` sentinels. Returns one
+    (1, nblocks * block) output per lane."""
+    n_arr, n_runs = len(flat), starts.shape[0]
+    col_blk = (n_runs, LANE_TILE)
+    return pl.pallas_call(
+        functools.partial(_kway_kernel, n_arr=n_arr, n_runs=n_runs,
+                          block=block),
+        out_shape=tuple(jax.ShapeDtypeStruct((1, nblocks * block), x.dtype)
+                        for x in flat),
+        grid=(nblocks,),
+        in_specs=[pl.BlockSpec(col_blk, lambda k: (0, k // LANE_TILE),
+                               memory_space=pltpu.SMEM),
+                  pl.BlockSpec(col_blk,
+                               lambda k: (0, (k + 1) // LANE_TILE),
+                               memory_space=pltpu.SMEM)]
+        + [pl.BlockSpec(memory_space=pl.ANY)] * n_arr,
+        out_specs=tuple(pl.BlockSpec((1, block), lambda k: (0, k))
+                        for _ in range(n_arr)),
+        scratch_shapes=[pltpu.VMEM((2 * n_runs, 1, block + LANE_TILE),
+                                   x.dtype) for x in flat]
+        + [pltpu.SemaphoreType.DMA((2, n_arr, n_runs))],
+        interpret=interpret,
+    )(starts, starts, *flat)
+
+
 @functools.partial(jax.jit, static_argnames=("n_arr", "n_runs", "n_cmp",
                                              "max_values", "block",
                                              "interpret"))
@@ -225,41 +262,26 @@ def _kway_merge_jit(*arrs, n_arr, n_runs, n_cmp, max_values, block,
 
     ranks = kway_ranks(_cmp_runs(runs, n_cmp, max_values))
     bounds = jnp.arange(nblocks + 1, dtype=jnp.int32) * block
-    # flat layout: run r's lane at [base_r, base_r + ns[r]), then `block`
-    # sentinel fill slots — every segment DMA reads a full in-bounds window.
+    # flat layout: run r's lane at [base_r, base_r + ns[r]), then
+    # `block + 128` sentinel fill slots — every segment DMA reads a full
+    # in-bounds window (runmerge_kernel.pad_run).
     bases, off = [], 0
     for n_r in ns:
         bases.append(off)
-        off += n_r + block
+        off += n_r + block + LANE_TILE
+    # (n_runs, nblocks + 1): column j holds every run's segment start for
+    # output block j. Step k reads columns k and k+1 through two SMEM blocks
+    # of 128 columns of the same table (the whole table, prefetched into
+    # SMEM, outgrows it past ~2^22 output elements).
     starts = jnp.stack([
         jnp.int32(bases[r])
         + jnp.searchsorted(ranks[r], bounds, side="left").astype(jnp.int32)
         for r in range(n_runs)])
-    flat = [jnp.concatenate(
-        [jnp.concatenate([run[i], jnp.full((block,),
-                                           sentinel_for(run[i].dtype),
-                                           run[i].dtype)])
-         for run in runs])[None, :] for i in range(n_arr)]
-
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,
-        grid=(nblocks,),
-        in_specs=[pl.BlockSpec(memory_space=pltpu.ANY)] * n_arr,
-        out_specs=tuple(pl.BlockSpec((1, block), lambda k, s: (0, k))
-                        for _ in range(n_arr)),
-        scratch_shapes=[pltpu.VMEM((2 * n_runs, block), x.dtype)
-                        for x in runs[0]]
-        + [pltpu.SemaphoreType.DMA((2, n_arr, n_runs))],
-    )
-    out = pl.pallas_call(
-        functools.partial(_kway_kernel, n_arr=n_arr, n_runs=n_runs,
-                          block=block),
-        out_shape=tuple(jax.ShapeDtypeStruct((1, nblocks * block),
-                                             runs[0][i].dtype)
-                        for i in range(n_arr)),
-        grid_spec=grid_spec,
-        interpret=interpret,
-    )(starts, *flat)
+    starts = jnp.pad(starts, ((0, 0), (0, -(nblocks + 1) % LANE_TILE)))
+    flat = [concat_lanes([pad_run(run[i], block) for run in runs], axis=1)
+            for i in range(n_arr)]
+    out = kway_kernel_call(starts, *flat, nblocks=nblocks, block=block,
+                           interpret=interpret)
     return tuple(o[0, :total] for o in out)
 
 

@@ -44,7 +44,12 @@ from jax import lax
 
 __all__ = ["to_order_bits", "from_order_bits", "order_view",
            "lex_gt_lanes", "lex_rank_count", "lex_merge_take", "map_lanes",
-           "select_lanes", "sentinel_for"]
+           "lane_roll", "lane_partners", "select_lanes", "sentinel_for",
+           "where_sentinel", "concat_lanes", "LANE_TILE", "window_start",
+           "segment_window", "pad_run"]
+
+# lanes of one TPU vector tile; a DMA may only start on a multiple of it
+LANE_TILE = 128
 
 # Plain python ints, NOT module-level jnp scalars: these helpers run inside
 # Pallas kernel bodies, which refuse closed-over array constants. The
@@ -78,6 +83,36 @@ def sentinel_for(dtype):
     if jnp.issubdtype(dtype, jnp.floating):
         return jnp.array(jnp.nan, dtype)
     return jnp.array(jnp.iinfo(dtype).max, dtype)
+
+
+def _is_f32(x):
+    return jnp.dtype(x.dtype) == jnp.dtype(jnp.float32)
+
+
+def where_sentinel(keep, x):
+    """``jnp.where(keep, x, sentinel_for(x.dtype))`` that conserves float32
+    bits: the select runs on the uint32 bits, because inside a TPU kernel a
+    scalar float bitcast has no lowering and a float NaN scalar may be
+    canonicalised on its way into the vector."""
+    if _is_f32(x):
+        bits = lax.bitcast_convert_type(x, jnp.uint32)
+        return lax.bitcast_convert_type(
+            jnp.where(keep, bits, jnp.uint32(_F32_SENTINEL_BITS)), jnp.float32)
+    return jnp.where(keep, x, sentinel_for(x.dtype))
+
+
+def concat_lanes(xs, axis: int = 0):
+    """``jnp.concatenate`` that conserves float32 bits. XLA on TPU may lower
+    a float concatenate to padded operands joined by ``maximum``, which
+    canonicalises NaN payloads; the uint32 bits concatenate instead. The
+    barrier keeps the compiler from folding the bitcasts back into a float
+    concatenate."""
+    if _is_f32(xs[0]):
+        bits = lax.optimization_barrier(
+            [lax.bitcast_convert_type(x, jnp.uint32) for x in xs])
+        return lax.bitcast_convert_type(jnp.concatenate(bits, axis=axis),
+                                        jnp.float32)
+    return jnp.concatenate(xs, axis=axis)
 
 
 def to_order_bits(x, max_value: Optional[int] = None):
@@ -225,6 +260,46 @@ def lex_merge_take(a_lanes, b_lanes):
 def map_lanes(fn, arrs):
     """Apply ``fn`` (a partner shuffle: roll/flip/...) to every lane."""
     return [fn(a) for a in arrs]
+
+
+def lane_roll(a, shift: int):
+    """``jnp.roll(a, shift, axis=-1)`` as the TPU's lane rotate
+    (``pltpu.roll``, which takes non-negative shifts only; ``shift`` may be
+    a loop value). Interpret mode evaluates it as ``jnp.roll``."""
+    from jax.experimental.pallas import tpu as pltpu
+    axis = a.ndim - 1
+    return pltpu.roll(a, shift % a.shape[axis], axis)
+
+
+def window_start(start):
+    """The lane-tile boundary at or below ``start``, where the DMA of a
+    segment that starts at ``start`` begins: a DMA may only start on a tile
+    boundary. ``start`` is non-negative."""
+    from jax.experimental import pallas as pl
+    return pl.multiple_of(start - (start & (LANE_TILE - 1)), LANE_TILE)
+
+
+def segment_window(win, start, cnt, block: int):
+    """The segment ``[start, start + cnt)`` out of the ``(1, block + 128)``
+    window fetched from :func:`window_start`: rotated to lane 0, cut to
+    ``block`` lanes, and masked past ``cnt`` to the sentinel."""
+    seg = lane_roll(win, -(start & (LANE_TILE - 1)))[:, :block]
+    col = lax.broadcasted_iota(jnp.int32, (1, block), 1)
+    return where_sentinel(col < cnt, seg)
+
+
+def pad_run(a, block: int):
+    """Run ``a`` as a ``(1, n + block + 128)`` row with sentinel fill: a
+    segment window starts up to one lane tile before its segment and spans
+    ``block + 128`` lanes, so the last one reaches that far past the run."""
+    fill = jnp.full((block + LANE_TILE,), sentinel_for(a.dtype), a.dtype)
+    return concat_lanes([a, fill])[None, :]
+
+
+def lane_partners(arrs, up, j: int):
+    """Every lane's partner at distance ``j``: lane ``i`` reads lane
+    ``i + j`` where ``up`` is True and lane ``i - j`` elsewhere."""
+    return [jnp.where(up, lane_roll(a, -j), lane_roll(a, j)) for a in arrs]
 
 
 def select_lanes(mask, on_true, on_false):

@@ -8,12 +8,13 @@ larger half in the right. ``core/blocksort.py`` alternates even/odd pairings
 of this kernel until the whole row is globally sorted, exactly as OETS
 alternates even/odd lane pairings.
 
-The merge itself is a bitonic merge network specialised for asc++asc input:
-one reflected compare-exchange (partner ``(2B-1) - i``, i.e. the lane-reversed
-array) splits the pair into a low half and a high half, then ``log2(B)``
-XOR-partner stages (the same two-``roll`` bit-select as the bitonic sort
-kernel) finish each half. ``log2(2B)`` phases total, all lane-parallel VPU
-work, no gather/scatter. ``block`` must be a power of two (the orchestrator
+The merge itself is Batcher's odd-even merge network, which takes asc++asc
+input as it is: one compare-exchange at distance ``B`` (lane ``i`` against
+``i + B``), then ``log2(B)`` stages at distances ``B/2 .. 1`` (the same
+two-rotate partner select as the bitonic sort kernel, with the lanes that
+have no partner in the window left alone). ``log2(2B)`` phases total, all
+lane-parallel VPU work, no gather/scatter and no lane reversal (a reverse
+has no TPU lowering). ``block`` must be a power of two (the orchestrator
 guarantees it).
 
 Variadic like the in-block kernels: ``merge_adjacent_lex_pallas(*arrs)``
@@ -29,10 +30,13 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
-from .lex import lex_gt_lanes, map_lanes, select_lanes
+from .lex import (lane_partners, lane_roll, lex_gt_lanes, map_lanes,
+                  select_lanes)
 
 __all__ = [
+    "VMEM_LIMIT",
     "merge_rows_lex_kernel",
     "merge_adjacent_lex_pallas",
     "merge_adjacent_pallas",
@@ -40,42 +44,78 @@ __all__ = [
 ]
 
 
-def _merge_network(arrs, block):
-    """Merge (RB, 2*block) rows whose halves are each sorted ascending."""
-    col = lax.broadcasted_iota(jnp.int32, arrs[0].shape, 1)
+# Scoped VMEM the kernel may use. At blocksort's largest blocks (8 rows x
+# 2 * 32Ki lanes key-only, 2 * 8Ki lanes for 4 arrays) the double-buffered
+# in/out blocks plus one stage's temporaries take 16.6-17 MiB, just over the
+# compiler's default 16 MiB scope; a TPU v5e core has 128 MiB of VMEM.
+VMEM_LIMIT = 32 * 1024 * 1024
 
-    # Reflected stage: compare lane i with lane (2B-1)-i, min to the low half.
-    # Turns asc++asc into low-half/high-half, each bitonic. The compare is
-    # full-tuple lex (see kernels/lex.py): trailing payload lanes break ties,
-    # so padding tuples (sentinel, ..., sentinel) stay strictly maximal and
-    # can never displace a real payload that shares the sentinel key.
-    partners = map_lanes(lambda a: jnp.flip(a, axis=1), arrs)
+
+def _first_stage(arrs, col, block):
+    """Stage k = B: lane i < B against lane i + B, min to the low lane. The
+    compare is full-tuple lex (see kernels/lex.py): trailing payload lanes
+    break ties, so padding tuples (sentinel, ..., sentinel) stay strictly
+    maximal and can never displace a real payload that shares the sentinel
+    key."""
+    partners = map_lanes(lambda a: lane_roll(a, block), arrs)
     lower = col < block
-    swap = jnp.where(lower, lex_gt_lanes(arrs, partners),
-                     lex_gt_lanes(partners, arrs))
-    arrs = select_lanes(swap, partners, arrs)
+    swap = ((lower & lex_gt_lanes(arrs, partners))
+            | (~lower & lex_gt_lanes(partners, arrs)))
+    return tuple(select_lanes(swap, partners, arrs))
 
-    # XOR-partner clean-up stages, ascending everywhere. j < block, so the
-    # rolls never cross the half boundary for any lane's true partner.
-    j = block // 2
-    while j >= 1:
-        bit_unset = (col & j) == 0
-        partners = [
-            jnp.where(bit_unset, jnp.roll(a, -j, axis=1), jnp.roll(a, j, axis=1))
-            for a in arrs
-        ]
-        swap = jnp.where(bit_unset, lex_gt_lanes(arrs, partners),
-                         lex_gt_lanes(partners, arrs))
-        arrs = select_lanes(swap, partners, arrs)
-        j //= 2
-    return arrs
+
+def _stage(arrs, col, block, t):
+    """Stage t of k = B/2 .. 1 (k = B >> (t + 1), t may be a loop value):
+    lane i with bit k set takes the min against i + k, lane i + k the max;
+    lanes with no partner in the window (the first k and the last k) stay.
+    Swaps combine as boolean ops: a select between boolean vectors has no
+    TPU lowering."""
+    k = block >> (t + 1)
+    bit_set = (col & k) != 0
+    low = bit_set & (col < 2 * block - k)
+    high = ~bit_set & (col >= 2 * k)
+    partners = lane_partners(arrs, bit_set, k)
+    swap = ((low & lex_gt_lanes(arrs, partners))
+            | (high & lex_gt_lanes(partners, arrs)))
+    return tuple(select_lanes(swap, partners, arrs))
+
+
+def _merge_network(arrs, block):
+    """Merge (RB, 2*block) rows whose halves are each sorted ascending —
+    Batcher's odd-even merge, ``log2(2B)`` stages of lane rotates. The
+    stages after the first run as a loop with k a loop value, which keeps
+    one stage in the kernel's code instead of log2(B)."""
+    col = lax.broadcasted_iota(jnp.int32, arrs[0].shape, 1)
+    # Float tuples can compare equal with different bits (-0.0 and +0.0,
+    # NaN payloads). The window position then breaks the tie, so those keep
+    # the merge oracle's order (left half first, each half in order).
+    floats = any(jnp.issubdtype(a.dtype, jnp.floating) for a in arrs)
+    arrs = tuple(arrs) + ((col,) if floats else ())
+    arrs = _first_stage(arrs, col, block)
+    arrs = lax.fori_loop(0, block.bit_length() - 1,
+                         lambda t, a: _stage(a, col, block, t), arrs)
+    return list(arrs[:-1] if floats else arrs)
 
 
 def merge_rows_lex_kernel(*refs, block):
+    """The network of :func:`_merge_network`, run in place on the output
+    refs: a loop that carried the (RB, 2*block) windows as values would need
+    VMEM for two copies of them."""
     n = len(refs) // 2
-    out = _merge_network(tuple(r[...] for r in refs[:n]), block)
-    for r, o in zip(refs[n:], out):
-        r[...] = o
+    outs = refs[n:]
+    col = lax.broadcasted_iota(jnp.int32, outs[0].shape, 1)
+
+    def store(arrs):
+        for o, a in zip(outs, arrs):
+            o[...] = a
+
+    store(_first_stage(tuple(r[...] for r in refs[:n]), col, block))
+
+    def body(t, carry):
+        store(_stage(tuple(o[...] for o in outs), col, block, t))
+        return carry
+
+    lax.fori_loop(0, block.bit_length() - 1, body, 0)
 
 
 def _row_block(rows: int) -> int:
@@ -110,6 +150,7 @@ def merge_adjacent_lex_pallas(*arrs, block: int, interpret: bool = False,
         grid=(rows // rb, npairs),
         in_specs=[spec] * len(arrs),
         out_specs=tuple([spec] * len(arrs)),
+        compiler_params=pltpu.CompilerParams(vmem_limit_bytes=VMEM_LIMIT),
         interpret=interpret,
     )(*arrs)
 

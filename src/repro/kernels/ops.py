@@ -49,7 +49,8 @@ These wrappers handle everything the raw kernels require of their caller:
 lane padding (cols -> multiple of 128 for OETS, next pow2 >= 128 for
 bitonic) with per-dtype lex-maximal sentinels so padding sinks to the row
 tail, sublane padding (rows -> multiple of the 8-row block), and automatic
-``interpret=True`` on CPU (this container), compiled on TPU.
+``interpret=True`` on any backend but TPU (the CPU the tests run on),
+compiled on TPU.
 
 Sentinel / dtype contract: padding uses the dtype's lex-maximal value under
 the canonical total order of ``kernels/lex.py`` (``iinfo.max`` for ints —
@@ -86,7 +87,7 @@ from .bitonic_kernel import bitonic_rows_lex_pallas
 from .distribute_kernel import distribute_rows_pallas
 from .keypack import (merge_take_packed, pack_rank_keys, plan_pack,
                       unpack_rank_keys)
-from .lex import lex_merge_take, sentinel_for
+from .lex import concat_lanes, lex_merge_take, sentinel_for
 from .oets_kernel import oets_rows_lex_pallas
 from .partition_kernel import partition_rows_pallas
 from .kway_kernel import merge_runs_kway_pallas, merge_runs_kway_take
@@ -156,7 +157,7 @@ def _pad_cols(x, target):
     if pad == 0:
         return x
     fill = jnp.full((x.shape[0], pad), _sentinel(x.dtype), x.dtype)
-    return jnp.concatenate([x, fill], axis=1)
+    return concat_lanes([x, fill], axis=1)
 
 
 def _pad_rows(x, multiple):
@@ -164,7 +165,7 @@ def _pad_rows(x, multiple):
     if pad == 0:
         return x
     fill = jnp.zeros((pad, x.shape[1]), x.dtype)
-    return jnp.concatenate([x, fill], axis=0)
+    return concat_lanes([x, fill], axis=0)
 
 
 def _next_pow2(n):

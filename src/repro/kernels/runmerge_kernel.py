@@ -14,8 +14,8 @@ This kernel keeps the combine in VMEM tiles instead:
   2. **Per-block VMEM merge**: each grid step DMAs its two segments (via
      scalar-prefetched starts — the segments land at data-dependent offsets
      no BlockSpec can express), masks the tails to the lex-maximal sentinel
-     tuple, and runs the same asc++asc bitonic merge network the cross-block
-     kernel uses (``merge_kernel._merge_network``) on the ``2*block`` window;
+     tuple, and runs the same asc++asc merge network the cross-block kernel
+     uses (``merge_kernel._merge_network``) on the ``2*block`` window;
      the low half is the finished output block. No HBM scatter anywhere.
 
 Variadic like every engine in this package: lanes merge as one lex tuple
@@ -25,10 +25,13 @@ diagonal on the leading compare lanes only; the in-block network still
 compares the full tuple, which is consistent because the compare prefix is
 an order-preserving refinement.
 
-Both runs are padded with ``block`` sentinel elements so every segment DMA
-reads a full window; output blocks beyond ``|a|+|b|`` hold sentinel fill and
-are sliced off. Equal tuples are interchangeable values, so the output is
-bit-identical to the lane-wise ``lex_merge_take`` oracle.
+A DMA may only start on a 128-lane tile boundary, so each segment is fetched
+as the ``block + 128``-lane window that starts at the tile boundary below it
+and rotated into place in VMEM. Both runs are padded with ``block + 128``
+sentinel elements so every such window stays in bounds; output blocks beyond
+``|a|+|b|`` hold sentinel fill and are sliced off. Equal tuples are
+interchangeable values, so the output is bit-identical to the lane-wise
+``lex_merge_take`` oracle.
 """
 
 from __future__ import annotations
@@ -37,12 +40,11 @@ import functools
 
 import jax
 import jax.numpy as jnp
-from jax import lax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from .keypack import lex_searchsorted, packed_cmp_lanes
-from .lex import sentinel_for
+from .lex import LANE_TILE, pad_run, segment_window, window_start
 from .merge_kernel import _merge_network
 
 __all__ = ["DEFAULT_MERGE_BLOCK", "merge_runs_lex_pallas", "merge_runs_pallas"]
@@ -63,14 +65,12 @@ def _runmerge_kernel(starts_ref, *refs, n_arr, block):
 
     copies = []
     for i in range(n_arr):
-        ca = pltpu.make_async_copy(a_refs[i].at[:, pl.ds(sa, block)],
-                                   scr[i].at[:, 0:block], sem.at[2 * i])
-        cb = pltpu.make_async_copy(b_refs[i].at[:, pl.ds(sb, block)],
-                                   scr[i].at[:, block:2 * block],
-                                   sem.at[2 * i + 1])
-        ca.start()
-        cb.start()
-        copies += [ca, cb]
+        for side, (refs_, s) in enumerate(((a_refs, sa), (b_refs, sb))):
+            c = pltpu.make_async_copy(
+                refs_[i].at[:, pl.ds(window_start(s), block + LANE_TILE)],
+                scr[i].at[side], sem.at[2 * i + side])
+            c.start()
+            copies.append(c)
     for c in copies:
         c.wait()
 
@@ -78,17 +78,13 @@ def _runmerge_kernel(starts_ref, *refs, n_arr, block):
     # Positions past each segment's count are masked to the sentinel tuple
     # (lex-maximal under the full-tuple compare), so both halves stay sorted
     # ascending and the fills sink past every real element of the block.
-    col = lax.broadcasted_iota(jnp.int32, (1, 2 * block), 1)
-    valid = jnp.where(col < block, col < ea - sa, col - block < eb - sb)
-    arrs = tuple(jnp.where(valid, s[...], sentinel_for(s.dtype)) for s in scr)
+    arrs = tuple(
+        jnp.concatenate([segment_window(s[0], sa, ea - sa, block),
+                         segment_window(s[1], sb, eb - sb, block)], axis=1)
+        for s in scr)
     merged = _merge_network(arrs, block)
     for r, m in zip(out_refs, merged):
         r[...] = m[:, :block]
-
-
-def _pad_run(a, block):
-    fill = jnp.full((block,), sentinel_for(a.dtype), a.dtype)
-    return jnp.concatenate([a, fill])[None, :]
 
 
 @functools.partial(jax.jit, static_argnames=("n_arr", "n_cmp", "max_values",
@@ -118,10 +114,11 @@ def _merge_runs_jit(*arrs, n_arr, n_cmp, max_values, block, interpret):
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
         grid=(nblocks,),
-        in_specs=[pl.BlockSpec(memory_space=pltpu.ANY)] * (2 * n_arr),
+        in_specs=[pl.BlockSpec(memory_space=pl.ANY)] * (2 * n_arr),
         out_specs=tuple(pl.BlockSpec((1, block), lambda k, s: (0, k))
                         for _ in range(n_arr)),
-        scratch_shapes=[pltpu.VMEM((1, 2 * block), a.dtype) for a in a_lanes]
+        scratch_shapes=[pltpu.VMEM((2, 1, block + LANE_TILE), a.dtype)
+                        for a in a_lanes]
         + [pltpu.SemaphoreType.DMA((2 * n_arr,))],
     )
     out = pl.pallas_call(
@@ -130,8 +127,8 @@ def _merge_runs_jit(*arrs, n_arr, n_cmp, max_values, block, interpret):
                         for a in a_lanes),
         grid_spec=grid_spec,
         interpret=interpret,
-    )(starts, *[_pad_run(a, block) for a in a_lanes],
-      *[_pad_run(b, block) for b in b_lanes])
+    )(starts, *[pad_run(a, block) for a in a_lanes],
+      *[pad_run(b, block) for b in b_lanes])
     return tuple(o[0, :total] for o in out)
 
 

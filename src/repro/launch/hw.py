@@ -1,7 +1,8 @@
 """Target-hardware constants (TPU v5e) used by the roofline analysis.
 
-This container executes on CPU; these numbers parameterize the *model* of
-the machine the dry-run compiles for. Sources: assignment spec.
+These numbers parameterize the *model* of the machine the dry-run compiles
+for; they are not keyed by device kind and no measurement is divided by
+them. Sources: assignment spec.
 """
 
 PEAK_FLOPS_BF16 = 197e12     # per chip, bf16

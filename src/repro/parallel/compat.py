@@ -1,87 +1,32 @@
-"""jax version compatibility for the mesh APIs the sharding layer uses.
+"""The mesh APIs the sharding layer uses, under one import path.
 
-The codebase targets the current mesh API (``jax.sharding.AxisType``,
-``jax.set_mesh``, ``jax.sharding.get_abstract_mesh``, ``axis_types=`` on
-mesh constructors); older jaxlib pins (this container ships 0.4.37) predate
-all four. Everything else in the repo imports the modern spelling from here
-so the fallback logic lives in exactly one place.
+Plain re-exports of the jax (0.9) spellings — ``jax.shard_map``,
+``jax.sharding.AxisType``, ``jax.make_mesh(..., axis_types=)``,
+``jax.set_mesh``, ``jax.lax.axis_size``, ``jax.sharding.get_abstract_mesh``
+— plus two small conveniences built on them.
 """
 
 from __future__ import annotations
 
 import jax
-from jax.sharding import Mesh
+from jax.sharding import AxisType, Mesh, get_abstract_mesh
 
 __all__ = ["AxisType", "make_mesh", "mesh_from_devices", "set_mesh",
            "get_abstract_mesh", "shard_map", "shard_map_norep", "axis_size"]
 
-try:
-    shard_map = jax.shard_map
-except AttributeError:  # pre-0.6 spelling
-    from jax.experimental.shard_map import shard_map
+shard_map = jax.shard_map
+make_mesh = jax.make_mesh
+set_mesh = jax.set_mesh
+axis_size = jax.lax.axis_size
 
 
 def shard_map_norep(f, **kw):
-    """``shard_map`` with the replication checker disabled — required when
-    the body contains ops without a replication rule (``pallas_call``, the
-    interpret-mode local sorts of ``core/distributed``). The flag was
-    renamed ``check_rep`` -> ``check_vma`` across jax versions; try both."""
-    for flag in ("check_rep", "check_vma"):
-        try:
-            return shard_map(f, **kw, **{flag: False})
-        except TypeError:
-            continue
-    return shard_map(f, **kw)
-
-
-def axis_size(axis_name):
-    """``lax.axis_size`` with a psum(1) fallback for jax versions without it
-    (inside collectives the sum of ones is constant-folded to the size)."""
-    fn = getattr(jax.lax, "axis_size", None)
-    if fn is not None:
-        return fn(axis_name)
-    return jax.lax.psum(1, axis_name)
-
-try:
-    from jax.sharding import AxisType
-    _HAS_AXIS_TYPES = True
-except ImportError:  # pre-explicit-sharding jax
-    import enum
-
-    class AxisType(enum.Enum):
-        Auto = "auto"
-        Explicit = "explicit"
-        Manual = "manual"
-
-    _HAS_AXIS_TYPES = False
-
-
-def make_mesh(axis_shapes, axis_names, axis_types=None):
-    """``jax.make_mesh`` that tolerates jax versions without axis_types."""
-    if _HAS_AXIS_TYPES and axis_types is not None:
-        return jax.make_mesh(axis_shapes, axis_names, axis_types=axis_types)
-    return jax.make_mesh(axis_shapes, axis_names)
+    """``shard_map`` with the replication checker off — required when the
+    body contains ops without a replication rule (``pallas_call``, the
+    interpret-mode local sorts of ``core/distributed``)."""
+    return jax.shard_map(f, check_vma=False, **kw)
 
 
 def mesh_from_devices(devices, axis_names, axis_types=None):
-    """``jax.sharding.Mesh`` from an explicit device array, same tolerance."""
-    if _HAS_AXIS_TYPES and axis_types is not None:
-        return Mesh(devices, axis_names, axis_types=axis_types)
-    return Mesh(devices, axis_names)
-
-
-def set_mesh(mesh):
-    """Context manager activating ``mesh`` as the ambient mesh."""
-    fn = getattr(jax, "set_mesh", None)
-    if fn is not None:
-        return fn(mesh)
-    return mesh  # pre-0.5: Mesh is itself the thread-resources context
-
-
-def get_abstract_mesh():
-    """The ambient (abstract) mesh; ``.empty`` when none is active."""
-    fn = getattr(jax.sharding, "get_abstract_mesh", None)
-    if fn is not None:
-        return fn()
-    from jax._src import mesh as mesh_lib
-    return mesh_lib.thread_resources.env.physical_mesh
+    """``jax.sharding.Mesh`` from an explicit device array."""
+    return Mesh(devices, axis_names, axis_types=axis_types)

@@ -76,3 +76,29 @@ def test_mesh_factories():
     assert set(m.axis_names) == {"data", "model"}
     e = make_elastic_mesh(1, model_parallel=4)
     assert e.size == 1
+
+
+@pytest.mark.parametrize("env_dir", [None, "elsewhere"],
+                         ids=["repo-cache", "env-cache"])
+def test_use_compile_cache(monkeypatch, tmp_path, env_dir):
+    """Without JAX_COMPILATION_CACHE_DIR the cache goes to the fixed
+    <repo>/.jax_cache; with it, nothing is set in code and the directory
+    named there is reported."""
+    import os
+    from repro.launch import cache
+    before = jax.config.jax_compilation_cache_dir
+    monkeypatch.setattr(jax.config, "update",
+                        lambda name, value: seen.update({name: value}))
+    seen = {}
+    if env_dir is None:
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+        assert cache.use_compile_cache() == cache.REPO_CACHE_DIR
+        assert seen == {"jax_compilation_cache_dir": cache.REPO_CACHE_DIR}
+        repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+        assert cache.REPO_CACHE_DIR == os.path.join(repo, ".jax_cache")
+    else:
+        want = str(tmp_path / env_dir)
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", want)
+        assert cache.use_compile_cache() == want
+        assert seen == {}
+    assert jax.config.jax_compilation_cache_dir == before
