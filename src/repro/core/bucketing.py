@@ -42,6 +42,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from ..runtime.trace import span
 from . import packing
 from .bitonic import bitonic_sort
 from .oets import oets_sort
@@ -166,28 +167,37 @@ def _fused_sort_packed(keys, *, capacity: int, algorithm: str):
     from ..kernels.ops import _scatter_to_buckets, distribute
     n, lanes = keys.shape
     num_buckets = 4 * lanes + 1
-    dest, rank, counts = distribute(keys)
-    buckets = _scatter_to_buckets(keys, dest, rank, num_buckets=num_buckets,
-                                  capacity=capacity)
-    counts_c = jnp.minimum(counts, capacity)
-    sorted_keys = sort_buckets(buckets, algorithm, counts=counts_c)
+    # one named scope per stage: the device trace's ops carry these names
+    with jax.named_scope("distribute"):
+        dest, rank, counts = distribute(keys)
+    with jax.named_scope("bucket_scatter"):
+        buckets = _scatter_to_buckets(keys, dest, rank,
+                                      num_buckets=num_buckets,
+                                      capacity=capacity)
+        counts_c = jnp.minimum(counts, capacity)
+    with jax.named_scope("bucket_sort"):
+        sorted_keys = sort_buckets(buckets, algorithm, counts=counts_c)
     # compaction: bucket b's i-th real word lands at offset[b] + i — the
     # concatenation-in-length-order of the paper's phase 4, as one scatter
-    offsets = jnp.cumsum(counts_c) - counts_c
-    slot_in = jnp.arange(capacity, dtype=jnp.int32)
-    valid = slot_in[None, :] < counts_c[:, None]
-    pos = jnp.where(valid, offsets[:, None] + slot_in[None, :],
-                    num_buckets * capacity).reshape(-1)
-    flat_keys = jnp.full((num_buckets * capacity + 1, lanes),
-                         packing.SENTINEL_U32, jnp.uint32
-                         ).at[pos].set(sorted_keys.reshape(-1, lanes))
-    blen = jnp.broadcast_to(jnp.arange(num_buckets, dtype=jnp.int32)[:, None],
-                            (num_buckets, capacity)).reshape(-1)
-    flat_lens = jnp.zeros((num_buckets * capacity + 1,), jnp.int32
-                          ).at[pos].set(blen)
-    m = num_buckets * capacity
-    packed = pack_shortlex(flat_lens[:m], flat_keys[:m])
-    return flat_lens[:m], flat_keys[:m], counts, tuple(packed.lanes)
+    with jax.named_scope("compact"):
+        offsets = jnp.cumsum(counts_c) - counts_c
+        slot_in = jnp.arange(capacity, dtype=jnp.int32)
+        valid = slot_in[None, :] < counts_c[:, None]
+        pos = jnp.where(valid, offsets[:, None] + slot_in[None, :],
+                        num_buckets * capacity).reshape(-1)
+        flat_keys = jnp.full((num_buckets * capacity + 1, lanes),
+                             packing.SENTINEL_U32, jnp.uint32
+                             ).at[pos].set(sorted_keys.reshape(-1, lanes))
+        blen = jnp.broadcast_to(
+            jnp.arange(num_buckets, dtype=jnp.int32)[:, None],
+            (num_buckets, capacity)).reshape(-1)
+        flat_lens = jnp.zeros((num_buckets * capacity + 1,), jnp.int32
+                              ).at[pos].set(blen)
+        m = num_buckets * capacity
+        flat_lens, flat_keys = flat_lens[:m], flat_keys[:m]
+    with jax.named_scope("rank_keys"):
+        packed = pack_shortlex(flat_lens, flat_keys)
+    return flat_lens, flat_keys, counts, tuple(packed.lanes)
 
 
 def sorted_packed(keys, algorithm: str = "pallas",
@@ -224,14 +234,19 @@ def sorted_packed(keys, algorithm: str = "pallas",
         return lens, keys, tuple(pack_shortlex(lens, keys).lanes)
     if capacity is None:
         from ..kernels.ops import distribute
-        _, _, counts = distribute(keys)
-        capacity = max(1, int(jnp.max(counts)))
-    flat_lens, flat_keys, counts, packed = _fused_sort_packed(
-        keys, capacity=capacity, algorithm=algorithm)
-    true_max = int(jnp.max(counts))
+        with span("dispatch", program="distribute"):
+            _, _, counts = distribute(keys)
+        with span("sync", what="capacity"):
+            capacity = max(1, int(jnp.max(counts)))
+    with span("dispatch", program="_fused_sort_packed"):
+        flat_lens, flat_keys, counts, packed = _fused_sort_packed(
+            keys, capacity=capacity, algorithm=algorithm)
+    with span("sync", what="max_count"):
+        true_max = int(jnp.max(counts))
     if true_max > capacity:
-        ln = int(jnp.argmax(counts))
-        dropped = int(jnp.sum(jnp.maximum(counts - capacity, 0)))
+        with span("sync", what="overflow"):
+            ln = int(jnp.argmax(counts))
+            dropped = int(jnp.sum(jnp.maximum(counts - capacity, 0)))
         if on_overflow == "raise":
             raise CapacityOverflow(
                 f"bucket for length {ln} exceeds capacity {capacity}",
@@ -240,16 +255,18 @@ def sorted_packed(keys, algorithm: str = "pallas",
             log.warning("sorted_packed overflow: capacity %d -> %d "
                         "(lossless retry of the fused program)",
                         capacity, true_max)
-            flat_lens, flat_keys, counts, packed = _fused_sort_packed(
-                keys, capacity=true_max, algorithm=algorithm)
+            with span("dispatch", program="_fused_sort_packed"):
+                flat_lens, flat_keys, counts, packed = _fused_sort_packed(
+                    keys, capacity=true_max, algorithm=algorithm)
         else:
             log.warning("sorted_packed overflow: dropping %d element(s) "
                         "past capacity %d (bucket for length %d needs %d)",
                         dropped, capacity, ln, true_max)
             n = n - dropped
-    if not return_packed:
-        return flat_lens[:n], flat_keys[:n]
-    return flat_lens[:n], flat_keys[:n], tuple(p[:n] for p in packed)
+    with span("dispatch", program="slice_outputs"):
+        if not return_packed:
+            return flat_lens[:n], flat_keys[:n]
+        return flat_lens[:n], flat_keys[:n], tuple(p[:n] for p in packed)
 
 
 def bucketed_sort_words(words, algorithm: str = "oets") -> list:

@@ -70,6 +70,7 @@ from ..kernels.keypack import (lex_searchsorted, merge_take_packed,
                                packed_searchsorted)
 from ..kernels.ops import _sentinel
 from ..parallel.compat import axis_size
+from ..runtime.trace import span
 from .bitonic import bitonic_merge, bitonic_merge_lex
 
 __all__ = [
@@ -775,7 +776,8 @@ def distributed_chunked_sort_lex(keys, mesh=None, axis: str = "data",
             bnds = [jnp.asarray([0, int(r[0].shape[0])] + [int(
                 r[0].shape[0])] * (num - 1), jnp.int32) for r in lanes_rs]
         else:
-            splitters = _run_splitters(cmp_rs, num, oversample)
+            with span("sync", what="splitter_samples"):
+                splitters = _run_splitters(cmp_rs, num, oversample)
             bnds = []
             for cmp_r, r in zip(cmp_rs, lanes_rs):
                 pos = lex_searchsorted(cmp_r, splitters, side="right")
@@ -783,20 +785,23 @@ def distributed_chunked_sort_lex(keys, mesh=None, axis: str = "data",
                 bnds.append(jnp.concatenate(
                     [jnp.zeros((1,), jnp.int32),
                      pos.astype(jnp.int32), n_r]))
-        bnds = [[int(x) for x in bnd] for bnd in bnds]
-        per_dest = []
-        for d in range(num):
-            dev = devs[d % len(devs)]
-            sub_lanes, sub_cmps = [], []
-            for bnd, lanes, cmps in zip(bnds, lanes_rs, cmp_rs):
-                lo, hi = bnd[d], bnd[d + 1]
-                if hi <= lo:
-                    continue
-                sub_lanes.append(tuple(jax.device_put(x[lo:hi], dev)
-                                       for x in lanes))
-                sub_cmps.append(tuple(jax.device_put(c[lo:hi], dev)
-                                      for c in cmps))
-            per_dest.append((sub_lanes, sub_cmps))
+        with span("sync", what="run_boundaries"):
+            bnds = [[int(x) for x in bnd] for bnd in bnds]
+        # (destination, run, lo, hi) of every non-empty sub-run
+        pieces = [(d, r, bnd[d], bnd[d + 1]) for d in range(num)
+                  for r, bnd in enumerate(bnds) if bnd[d + 1] > bnd[d]]
+        row_bytes = [sum(x.dtype.itemsize for x in lanes + tuple(cmps))
+                     for lanes, cmps in zip(lanes_rs, cmp_rs)]
+        per_dest = [([], []) for _ in range(num)]
+        with span("run_exchange", slices=len(pieces),
+                  bytes=sum((hi - lo) * row_bytes[r]
+                            for _, r, lo, hi in pieces)):
+            for d, r, lo, hi in pieces:
+                dev = devs[d % len(devs)]
+                per_dest[d][0].append(tuple(jax.device_put(x[lo:hi], dev)
+                                            for x in lanes_rs[r]))
+                per_dest[d][1].append(tuple(jax.device_put(c[lo:hi], dev)
+                                            for c in cmp_rs[r]))
         return per_dest
 
     while True:

@@ -66,6 +66,7 @@ from jax import lax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from ..runtime.trace import span
 from .keypack import merge_take_packed, packed_cmp_lanes
 from .lex import (LANE_TILE, concat_lanes, pad_run, segment_window,
                   to_order_bits, window_start)
@@ -247,6 +248,11 @@ def kway_kernel_call(starts, *flat, nblocks, block, interpret=False):
                                    x.dtype) for x in flat]
         + [pltpu.SemaphoreType.DMA((2, n_arr, n_runs))],
         interpret=interpret,
+        # the custom call takes its HLO name from the innermost name scope
+        # around it: this one keeps it ``_kway_merge_jit.N`` inside the
+        # caller's ``kway_kernel`` scope, the name device traces have always
+        # shown for the kernel
+        name="_kway_merge_jit",
     )(starts, starts, *flat)
 
 
@@ -260,8 +266,9 @@ def _kway_merge_jit(*arrs, n_arr, n_runs, n_cmp, max_values, block,
     total = sum(ns)
     nblocks = -(-total // block)
 
-    ranks = kway_ranks(_cmp_runs(runs, n_cmp, max_values))
-    bounds = jnp.arange(nblocks + 1, dtype=jnp.int32) * block
+    # one named scope per stage: the device trace's ops carry these names
+    with jax.named_scope("kway_ranks"):
+        ranks = kway_ranks(_cmp_runs(runs, n_cmp, max_values))
     # flat layout: run r's lane at [base_r, base_r + ns[r]), then
     # `block + 128` sentinel fill slots — every segment DMA reads a full
     # in-bounds window (runmerge_kernel.pad_run).
@@ -273,16 +280,20 @@ def _kway_merge_jit(*arrs, n_arr, n_runs, n_cmp, max_values, block,
     # output block j. Step k reads columns k and k+1 through two SMEM blocks
     # of 128 columns of the same table (the whole table, prefetched into
     # SMEM, outgrows it past ~2^22 output elements).
-    starts = jnp.stack([
-        jnp.int32(bases[r])
-        + jnp.searchsorted(ranks[r], bounds, side="left").astype(jnp.int32)
-        for r in range(n_runs)])
-    starts = jnp.pad(starts, ((0, 0), (0, -(nblocks + 1) % LANE_TILE)))
-    flat = [concat_lanes([pad_run(run[i], block) for run in runs], axis=1)
-            for i in range(n_arr)]
-    out = kway_kernel_call(starts, *flat, nblocks=nblocks, block=block,
-                           interpret=interpret)
-    return tuple(o[0, :total] for o in out)
+    with jax.named_scope("kway_starts"):
+        bounds = jnp.arange(nblocks + 1, dtype=jnp.int32) * block
+        starts = jnp.stack([
+            jnp.int32(bases[r]) + jnp.searchsorted(
+                ranks[r], bounds, side="left").astype(jnp.int32)
+            for r in range(n_runs)])
+        starts = jnp.pad(starts, ((0, 0), (0, -(nblocks + 1) % LANE_TILE)))
+    with jax.named_scope("kway_pad"):
+        flat = [concat_lanes([pad_run(run[i], block) for run in runs], axis=1)
+                for i in range(n_arr)]
+    with jax.named_scope("kway_kernel"):
+        out = kway_kernel_call(starts, *flat, nblocks=nblocks, block=block,
+                               interpret=interpret)
+        return tuple(o[0, :total] for o in out)
 
 
 def merge_runs_kway_pallas(runs, n_cmp=None, max_values=None,
@@ -312,10 +323,11 @@ def merge_runs_kway_pallas(runs, n_cmp=None, max_values=None,
         return runs[0]
     if len(nonempty) == 1:
         return nonempty[0]
-    return _kway_merge_jit(*[x for r in nonempty for x in r],
-                           n_arr=len(runs[0]), n_runs=len(nonempty),
-                           n_cmp=n_cmp, max_values=max_values, block=block,
-                           interpret=interpret)
+    with span("dispatch", program="_kway_merge_jit"):
+        return _kway_merge_jit(*[x for r in nonempty for x in r],
+                               n_arr=len(runs[0]), n_runs=len(nonempty),
+                               n_cmp=n_cmp, max_values=max_values,
+                               block=block, interpret=interpret)
 
 
 def merge_kway_pallas(runs, block: int | None = None,

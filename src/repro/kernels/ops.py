@@ -83,6 +83,7 @@ from typing import NamedTuple
 import jax
 import jax.numpy as jnp
 
+from ..runtime.trace import span
 from .bitonic_kernel import bitonic_rows_lex_pallas
 from .distribute_kernel import distribute_rows_pallas
 from .keypack import (merge_take_packed, pack_rank_keys, plan_pack,
@@ -503,9 +504,10 @@ def merge_runs_lex(runs, engine: str = "auto", n_cmp: int | None = None,
                                       max_values=max_values,
                                       block=block_size,
                                       interpret=_auto_interpret(interpret))
-    return _kway_take_jit(*[x for r in nonempty for x in r],
-                          n_arr=len(runs[0]), n_runs=len(nonempty),
-                          n_cmp=n_cmp, max_values=max_values)
+    with span("dispatch", program="_kway_take_jit"):
+        return _kway_take_jit(*[x for r in nonempty for x in r],
+                              n_arr=len(runs[0]), n_runs=len(nonempty),
+                              n_cmp=n_cmp, max_values=max_values)
 
 
 def merge_sorted(a, b, engine: str = "auto", block_size: int | None = None,

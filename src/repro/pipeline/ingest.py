@@ -43,6 +43,7 @@ import numpy as np
 from ..core import packing
 from ..core.bucketing import sorted_packed
 from ..kernels.keypack import cmp_from_packed, packed_cmp_lanes, shortlex_max_values
+from ..runtime.trace import span
 from .manifest import RunManifest
 from .merge import merge_runs
 from .validate import check_chunked, keys_digest
@@ -157,10 +158,12 @@ def _ingest_chunk(chunk, chunk_id: int, *, algorithm: str, capacity,
         return sorted_run(chunk, algorithm=algorithm, capacity=capacity,
                           on_overflow=on_overflow)
 
-    if supervisor is not None:
-        run = supervisor.run_stage("ingest_chunk", launch)
-    else:
-        run = launch()
+    with span("ingest_chunk", chunk=chunk_id, rows=int(chunk.shape[0]),
+              capacity=capacity):
+        if supervisor is not None:
+            run = supervisor.run_stage("ingest_chunk", launch)
+        else:
+            run = launch()
     man = (RunManifest.from_run(run, chunk_id)
            if (store is not None or need_manifest) else None)
     if store is not None:
@@ -172,10 +175,13 @@ def _merged_run(runs, manifests=None, supervisor=None,
                 merge_engine: str = "auto") -> SortedRun:
     if len(runs) == 1:
         return runs[0]
-    merged = merge_runs([r.lanes() for r in runs], engine=merge_engine,
-                        cmp_runs=[r.cmp_lanes() for r in runs],
+    with span("dispatch", program="run_lanes"):
+        lanes = [r.lanes() for r in runs]
+        cmp_runs = [r.cmp_lanes() for r in runs]
+    merged = merge_runs(lanes, engine=merge_engine, cmp_runs=cmp_runs,
                         manifests=manifests, supervisor=supervisor)
-    return SortedRun.from_lanes(merged)
+    with span("dispatch", program="stack_lanes"):
+        return SortedRun.from_lanes(merged)
 
 
 def _stage_chunk(chunk):
@@ -241,18 +247,26 @@ def chunked_sort_packed(keys, chunk_size: int = DEFAULT_CHUNK,
     runs, manifests = [], []
     host_chunks = [keys[start: start + chunk_size]
                    for start in range(0, n, chunk_size)]
-    for ci, chunk in enumerate(_prefetch_map(_stage_chunk, host_chunks)):
-        cap = capacity if capacity is not None else int(chunk.shape[0])
-        run, man = _ingest_chunk(
-            chunk, ci, algorithm=algorithm, capacity=cap,
-            on_overflow=on_overflow, store=store, supervisor=supervisor,
-            need_manifest=validate != "off")
-        runs.append(run)
-        manifests.append(man)
-    merged = _merged_run(runs, manifests=manifests if track else None,
-                         supervisor=supervisor, merge_engine=merge_engine)
-    if validate != "off":
-        check_chunked(runs, manifests, merged, mode=validate)
+
+    def stage(item):
+        ci, chunk = item
+        with span("stage", chunk=ci, bytes=int(chunk.nbytes)):
+            return _stage_chunk(chunk)
+
+    with span("job", rows=n, chunks=len(host_chunks)):
+        for ci, chunk in enumerate(_prefetch_map(stage,
+                                                 enumerate(host_chunks))):
+            cap = capacity if capacity is not None else int(chunk.shape[0])
+            run, man = _ingest_chunk(
+                chunk, ci, algorithm=algorithm, capacity=cap,
+                on_overflow=on_overflow, store=store, supervisor=supervisor,
+                need_manifest=validate != "off")
+            runs.append(run)
+            manifests.append(man)
+        merged = _merged_run(runs, manifests=manifests if track else None,
+                             supervisor=supervisor, merge_engine=merge_engine)
+        if validate != "off":
+            check_chunked(runs, manifests, merged, mode=validate)
     return merged
 
 
@@ -303,18 +317,21 @@ def chunked_sort_words(words, chunk_size: int = DEFAULT_CHUNK,
               for i in range(0, len(words), chunk_size)]
     track = store is not None or validate != "off"
     runs, manifests = [], []
-    for ci, keys in enumerate(_prefetch_map(
-            lambda ws: jnp.asarray(packing.pack_words(ws, width=width)),
-            chunks)):
-        cap = capacity if capacity is not None else int(keys.shape[0])
-        run, man = _ingest_chunk(
-            keys, ci, algorithm=algorithm, capacity=cap,
-            on_overflow=on_overflow, store=store, supervisor=supervisor,
-            need_manifest=validate != "off")
-        runs.append(run)
-        manifests.append(man)
-    run = _merged_run(runs, manifests=manifests if track else None,
-                      supervisor=supervisor, merge_engine=merge_engine)
-    if validate != "off":
-        check_chunked(runs, manifests, run, mode=validate)
-    return packing.unpack_words(np.asarray(run.keys))
+    with span("job", rows=len(words), chunks=len(chunks)):
+        for ci, keys in enumerate(_prefetch_map(
+                lambda ws: jnp.asarray(packing.pack_words(ws, width=width)),
+                chunks)):
+            cap = capacity if capacity is not None else int(keys.shape[0])
+            run, man = _ingest_chunk(
+                keys, ci, algorithm=algorithm, capacity=cap,
+                on_overflow=on_overflow, store=store, supervisor=supervisor,
+                need_manifest=validate != "off")
+            runs.append(run)
+            manifests.append(man)
+        run = _merged_run(runs, manifests=manifests if track else None,
+                          supervisor=supervisor, merge_engine=merge_engine)
+        if validate != "off":
+            check_chunked(runs, manifests, run, mode=validate)
+        with span("sync", what="unpack_keys"):
+            host_keys = np.asarray(run.keys)
+    return packing.unpack_words(host_keys)

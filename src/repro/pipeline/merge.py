@@ -26,6 +26,7 @@ from __future__ import annotations
 
 from ..kernels.keypack import packed_cmp_lanes
 from ..kernels.ops import merge_runs_lex, merge_sorted_lex
+from ..runtime.trace import span
 
 __all__ = ["merge_two", "merge_runs"]
 
@@ -95,6 +96,7 @@ def merge_runs(runs, engine: str = "auto", max_values=None, cmp_runs=None,
         cmp_runs = [packed_cmp_lanes(list(r), max_values) for r in runs]
     ext = [tuple(c) + r for c, r in zip(cmp_runs, runs)]
     n_cmp = len(ext[0]) - arity
+    rows = sum(int(r[0].shape[0]) for r in runs)
 
     if engine != "tournament":
         ops_engine = "kernel" if engine == "kway_kernel" else "auto"
@@ -104,16 +106,19 @@ def merge_runs(runs, engine: str = "auto", max_values=None, cmp_runs=None,
                                   block_size=block_size,
                                   interpret=interpret)
 
-        if supervisor is None:
-            merged = combine(ext)
-        else:
-            merged = supervisor.run_stage("streaming_combine", combine, ext)
+        with span("streaming_combine", runs=len(ext), rows=rows):
+            if supervisor is None:
+                merged = combine(ext)
+            else:
+                merged = supervisor.run_stage("streaming_combine", combine,
+                                              ext)
         return tuple(merged[n_cmp:])
 
     def one_round(ext_rs):
-        nxt = [merge_sorted_lex(ext_rs[i], ext_rs[i + 1], n_cmp=n_cmp,
-                                interpret=interpret)
-               for i in range(0, len(ext_rs) - 1, 2)]
+        with span("merge_round", runs=len(ext_rs), rows=rows):
+            nxt = [merge_sorted_lex(ext_rs[i], ext_rs[i + 1], n_cmp=n_cmp,
+                                    interpret=interpret)
+                   for i in range(0, len(ext_rs) - 1, 2)]
         if len(ext_rs) % 2:
             nxt.append(ext_rs[-1])
         return nxt

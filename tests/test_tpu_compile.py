@@ -128,6 +128,21 @@ def test_kway_kernel_at_chunk_runs(one_chip):
         ((RUNS, cols), I32), *[((1, flat_len), U32)] * KWAY_ARRAYS)
 
 
+def test_kway_kernel_keeps_its_op_name_inside_its_scope(one_chip):
+    """The compiled k-way program names its Pallas custom call
+    ``_kway_merge_jit.N``, the operation name device traces show for the
+    kernel, while its metadata carries the ``kway_kernel`` scope."""
+    args = [jax.ShapeDtypeStruct((n,), U32, sharding=one_chip)
+            for n in (4096, 4096, 1328) for _ in range(5)]
+    hlo = jax.jit(lambda *a: merge_runs_kway_pallas(
+        [a[i:i + 5] for i in range(0, 15, 5)], n_cmp=4,
+        interpret=False)).lower(*args).compile().as_text()
+    calls = re.findall(r'%(\S+) = .*custom-call\(.*custom_call_target='
+                       r'"tpu_custom_call".*op_name="([^"]*)"', hlo)
+    assert [(re.sub(r"\.\d+$", "", name), "/kway_kernel/" in path)
+            for name, path in calls] == [("_kway_merge_jit", True)]
+
+
 _FLOAT_MERGES = {
     "runmerge": lambda a, b: merge_runs_lex_pallas([a], [b],
                                                    interpret=False)[0],
