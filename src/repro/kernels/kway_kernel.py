@@ -7,16 +7,19 @@ payoff the parallel-sorting survey calls out, and the merge profile of the
 authors' MPI follow-up). This module collapses the combine to one launch:
 
   1. **k-way diagonal split** (:func:`kway_ranks`, host jnp inside the same
-     jit): the merge-path ranks come from a *key tournament* — ceil(log2 k)
-     pairwise rank-merge rounds (``keypack.merge_take_packed``) over the
-     packed compare lanes plus a source-index lane, then one inverse-
-     permutation scatter. Only the 1-3 compare lanes ever move through the
-     rounds (the data lanes move exactly once, later), and the round count
-     keeps the search total at O(k) binary searches — the naive all-pairs
-     split is O(k^2) searches and collapses the XLA graph past k ~ 8. Ties
-     resolve by run index (lower run wins, the a-before-b protocol of
-     ``merge_take_packed`` applied along the tree), so the ranks are exactly
-     a permutation of ``[0, total)``. One ``searchsorted`` of
+     jit): the merge-path ranks are the inverse of the merge permutation,
+     through one scatter. A bitonic merge network over the runs' compare
+     lanes plus a source-index lane computes the permutation: ceil(log2 k)
+     rounds of compare-exchange stages over a (rows, 128) layout, each
+     partner one static roll away, with no gather and no sort. Only the
+     compare lanes move through it (the data lanes move exactly once,
+     later). Ties resolve by run index, then in-run index (the source
+     index is the last compare lane), so the ranks are exactly a
+     permutation of ``[0, total)``. On a TPU v5e the network replaced a
+     tournament of pairwise binary-search rounds whose ~2,400 dependent
+     small gathers took 443 ms a job at 7 x 32768 + 624 rows, against the
+     kernel's 20 ms; one ``lax.sort`` of the same lanes would take the
+     TPU compiler minutes at that size. One ``searchsorted`` of
      each run's ranks over the block boundaries turns them into per-block
      segment cursors, and those cursors ride into the kernel as SMEM blocks
      of 128 columns of the (run, block) table (grid step k reads columns k
@@ -48,9 +51,10 @@ prefix => equal tuple), which the pipeline's exact packings guarantee.
 
 :func:`merge_runs_kway_take` is the jnp tier of the same contract: off-TPU
 there is no DMA pipeline to hide latency behind, so op count is what rules —
-ONE fused ``lax.sort`` over the canonical order bits of the 1-3 compare
-lanes (+ an iota lane whose stable order encodes the run-index tie protocol)
-yields the merge permutation in a single dispatch, then ONE gather per lane.
+ONE fused ``lax.sort`` over the canonical order bits of the compare lanes
+(+ an iota lane whose stable order encodes the run-index tie protocol)
+yields the merge permutation of item 1 in a single dispatch, then ONE
+gather per lane.
 The data lanes move exactly once, versus the tournament's log2(k) passes of
 ~k separate jits over every lane. That is the engine
 ``ops.merge_runs_lex`` routes to off-TPU.
@@ -67,9 +71,9 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from ..runtime.trace import span
-from .keypack import merge_take_packed, packed_cmp_lanes
-from .lex import (LANE_TILE, concat_lanes, pad_run, segment_window,
-                  to_order_bits, window_start)
+from .keypack import packed_cmp_lanes
+from .lex import (LANE_TILE, concat_lanes, lex_gt_lanes, pad_run,
+                  segment_window, to_order_bits, window_start)
 from .merge_kernel import _merge_network
 
 __all__ = ["DEFAULT_KWAY_BLOCK", "kway_ranks", "merge_runs_kway_take",
@@ -79,6 +83,62 @@ __all__ = ["DEFAULT_KWAY_BLOCK", "kway_ranks", "merge_runs_kway_take",
 DEFAULT_KWAY_BLOCK = 256
 
 
+def _merge_perm(cmp_runs):
+    """Source index, in the concatenation of the sorted runs ``cmp_runs``,
+    of every slot of their k-way merge, ties broken by run index then in-run
+    index (the k-way tie protocol), as a bitonic merge network in plain jnp:
+    no gather and no sort.
+
+    Each run pads to ``P`` (a power of two >= 128) with the all-ones order
+    bits, the run count to a power of two ``K`` with all-pad runs, and every
+    slot carries its source index as a last compare lane (``total`` on
+    pads, above every real index), so real tuples are distinct and the
+    network's order is the stable one. Odd runs are reversed, so the blocks
+    alternate ascending and descending, and ceil(log2 k) bitonic merge
+    rounds sort the (K*P/128, 128) layout: one compare-exchange stage per
+    partner distance, the partner fetched by a static roll along lanes
+    (distance < 128) or rows. Compiled for a TPU v5e, one ``lax.sort`` of
+    the same 6 lanes at 7 x 32768 + 624 rows takes minutes, this network
+    seconds."""
+    nc = len(cmp_runs[0])
+    ns = [c[0].shape[0] for c in cmp_runs]
+    k, total = len(cmp_runs), sum(ns)
+    P = max(LANE_TILE, 1 << (max(ns) - 1).bit_length())
+    K = 1 << (k - 1).bit_length()
+    rows = (K * P) // LANE_TILE
+
+    def layout(runs, fill):
+        blocks = [jnp.pad(x, (0, P - x.shape[0]), constant_values=fill)
+                  for x in runs] + [jnp.full((P,), fill)] * (K - k)
+        blocks = [x[::-1] if r % 2 else x for r, x in enumerate(blocks)]
+        return jnp.concatenate(blocks).reshape(rows, LANE_TILE)
+
+    top = jnp.uint32(0xFFFFFFFF)  # the largest order bits
+    lanes = [layout([to_order_bits(c[i]) for c in cmp_runs], top)
+             for i in range(nc)]
+    src = jnp.arange(total, dtype=jnp.int32)
+    bases = [sum(ns[:r]) for r in range(k)]
+    lanes.append(layout([src[b:b + n] for b, n in zip(bases, ns)],
+                        jnp.int32(total)))
+    idx = (lax.broadcasted_iota(jnp.int32, (rows, LANE_TILE), 0) * LANE_TILE
+           + lax.broadcasted_iota(jnp.int32, (rows, LANE_TILE), 1))
+    size = 2 * P
+    while size <= K * P:
+        asc = (idx & size) == 0
+        d = size // 2
+        while d:
+            lower = (idx & d) == 0
+            shift, axis = (d, 1) if d < LANE_TILE else (d // LANE_TILE, 0)
+            part = [jnp.where(lower, jnp.roll(x, -shift, axis),
+                              jnp.roll(x, shift, axis)) for x in lanes]
+            # the lower slot of an ascending pair keeps the smaller tuple
+            take = (lower == asc) == lex_gt_lanes(lanes, part)
+            lanes = [jnp.where(take, y, x) for x, y in zip(lanes, part)]
+            d //= 2
+        size *= 2
+    return lanes[-1].reshape(-1)[:total]
+
+
 def kway_ranks(cmp_runs):
     """Merge-path rank of every element of every sorted run: a list of int32
     arrays (one per run) that together form a permutation of ``[0, total)``.
@@ -86,34 +146,20 @@ def kway_ranks(cmp_runs):
     ``cmp_runs[r]`` is run r's compare-lane tuple. Compare-equal elements
     order by run index (then by in-run index), so the ranks collide nowhere.
 
-    Computed as a key tournament: each run carries its flat source index as
-    a payload lane, adjacent pairs rank-merge (``merge_take_packed``,
-    a-before-b — the lower run index is always the left operand, so the tie
-    protocol composes along the tree) until one key sequence remains, and
-    the final ranks are its inverse permutation. ceil(log2 k) rounds moving
-    only the compare lanes + one int32 lane — O(k) binary searches total,
-    where ranking every run against every other would pay O(k^2)."""
-    cmp_runs = [list(c) for c in cmp_runs]
+    The ranks are the inverse of :func:`_merge_perm`'s permutation, through
+    one scatter. A tournament of pairwise binary-search rounds gives the
+    same permutation, but on a TPU v5e its ~2,400 dependent small gathers
+    took 443 ms a job at 7 x 32768 + 624 rows, 63% of the device time of
+    the whole sort; the merge network has no gather at all."""
+    cmp_runs = [tuple(c) for c in cmp_runs]
     ns = [c[0].shape[0] for c in cmp_runs]
-    bases, off = [], 0
-    for n_r in ns:
-        bases.append(off)
-        off += n_r
-    total = off
+    total = sum(ns)
     if len(cmp_runs) == 1:
         return [jnp.arange(total, dtype=jnp.int32)]
-    nc = len(cmp_runs[0])
-    ext = [c + [base + jnp.arange(n_r, dtype=jnp.int32)]
-           for c, base, n_r in zip(cmp_runs, bases, ns)]
-    while len(ext) > 1:
-        nxt = [merge_take_packed(ext[j], ext[j + 1], n_cmp=nc)
-               for j in range(0, len(ext) - 1, 2)]
-        if len(ext) % 2:
-            nxt.append(ext[-1])
-        ext = nxt
-    src = ext[0][nc]
-    ranks_flat = jnp.zeros((total,), jnp.int32).at[src].set(
-        jnp.arange(total, dtype=jnp.int32), unique_indices=True)
+    iota = jnp.arange(total, dtype=jnp.int32)
+    ranks_flat = jnp.zeros((total,), jnp.int32).at[_merge_perm(cmp_runs)].set(
+        iota, unique_indices=True)
+    bases = [sum(ns[:r]) for r in range(len(ns))]
     return [ranks_flat[b:b + n_r] for b, n_r in zip(bases, ns)]
 
 
@@ -133,10 +179,12 @@ def merge_runs_kway_take(runs, n_cmp=None, max_values=None):
     ``-0.0`` collapses onto ``+0.0`` and every NaN onto the canonical slot
     above ``+inf``, exactly the comparator the oracle uses) — with an iota
     lane riding along: stable ties keep concatenation order, which is run
-    index then in-run index, the k-way tie protocol. One fused sort op
-    beats any unrolled O(k) graph of binary-search rounds off-TPU, where
-    per-op dispatch dominates. Traceable; runs are sequences of equal-arity
-    lane tuples, any lengths."""
+    index then in-run index, the k-way tie protocol. It is the order the
+    Pallas tier's :func:`kway_ranks` computes with a merge network, which
+    compiles in seconds on a TPU where this sort would take minutes at its
+    sizes; off-TPU, where per-op dispatch dominates, one fused sort is the
+    cheaper program. Traceable; runs are sequences of equal-arity lane
+    tuples, any lengths."""
     runs = [list(r) for r in runs]
     cmp_runs = _cmp_runs(runs, n_cmp, max_values)
     nc = len(cmp_runs[0])

@@ -10,6 +10,7 @@ on this CPU container (block 128, a few hundred elements — still genuinely
 multi-block, so the double-buffered segment DMA is on the tested path).
 """
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
@@ -72,6 +73,47 @@ def test_kway_ranks_is_a_permutation(sizes):
     # within a run, ranks must ascend (runs are sorted)
     for r in ranks:
         assert np.all(np.diff(np.asarray(r)) > 0) or r.shape[0] <= 1
+
+
+def _ranks_oracle(cmp_runs):
+    """NumPy ranks: lexsort by compare lanes, then run index, then in-run
+    index, inverted."""
+    nc = len(cmp_runs[0])
+    lanes = [np.concatenate([np.asarray(c[i]) for c in cmp_runs])
+             for i in range(nc)]
+    run = np.concatenate([np.full(c[0].shape[0], r)
+                          for r, c in enumerate(cmp_runs)])
+    inrun = np.concatenate([np.arange(c[0].shape[0]) for c in cmp_runs])
+    order = np.lexsort((inrun, run) + tuple(reversed(lanes)))
+    ranks = np.empty_like(order)
+    ranks[order] = np.arange(order.size)
+    return ranks
+
+
+@pytest.mark.parametrize("nc", [1, 5])
+@pytest.mark.parametrize("k", [2, 3, 8, 16])
+def test_kway_ranks_match_numpy_oracle(k, nc):
+    """Heavy ties (lane values 0..3) and empty runs mixed in: the ranks are
+    exactly the NumPy lexsort's, ties broken by run then in-run index."""
+    rng = np.random.default_rng(100 * k + nc)
+    sizes = rng.integers(0, 40, k)
+    sizes[::3] = 0
+    sizes[1] = max(sizes[1], 1)
+    cmp_runs = [tuple(_sorted_run(rng, int(n), nc, hi=4)) for n in sizes]
+    got = np.concatenate([np.asarray(r) for r in kway_ranks(cmp_runs)])
+    np.testing.assert_array_equal(got, _ranks_oracle(cmp_runs))
+
+
+def test_kway_ranks_lower_to_no_gather_and_no_sort():
+    """At ds2-kway's shapes (7 x 32768 + 624 rows, 5 uint32 compare lanes)
+    the ranks lower to neither a gather nor a sort: a chain of binary-search
+    gathers was the combine's cost on the chip, and a sort of these lanes
+    takes the TPU compiler minutes."""
+    cmp_runs = [tuple(jax.ShapeDtypeStruct((n,), jnp.uint32)
+                      for _ in range(5)) for n in [32768] * 7 + [624]]
+    text = jax.jit(kway_ranks).lower(cmp_runs).as_text()
+    assert text.count("stablehlo.gather") == 0
+    assert text.count("stablehlo.sort") == 0
 
 
 # ---------------------------------------------------------------------------
