@@ -68,6 +68,7 @@ from jax import lax
 
 from ..kernels.keypack import (lex_searchsorted, merge_take_packed,
                                packed_searchsorted)
+from ..kernels.lex import LANE_TILE
 from ..kernels.ops import _sentinel
 from ..parallel.compat import axis_size
 from ..runtime.trace import span
@@ -637,27 +638,80 @@ def _chunk_devices(mesh, axis, devices):
     return list(jax.devices())
 
 
+def _slot_rows(n: int, num: int) -> int:
+    """Rows of one (run, destination) slot of the mesh exchange: a quarter
+    over a piece's even share, ``ceil(ceil(n / num) / num)``, rounded up to
+    the lane tile. It follows from ``n`` and the device count alone, so
+    every input of one size runs the same programs. On 24 paper-ds2 jobs
+    over 4 devices the fullest piece held 1.157x its even share."""
+    chunk = -(-n // num)
+    share = -(-chunk // num)
+    rows = -(-share * 5 // 4)
+    return -(-rows // LANE_TILE) * LANE_TILE
+
+
+@functools.partial(jax.jit, static_argnames=("oversample",))
+def _run_samples(cmp_lanes, oversample: int):
+    """``oversample`` evenly spaced rows of one sorted run's compare
+    lanes."""
+    n_r = cmp_lanes[0].shape[0]
+    pos = np.minimum(np.arange(oversample) * max(1, n_r // oversample),
+                     n_r - 1)
+    return tuple(lane[pos] for lane in cmp_lanes)
+
+
 def _run_splitters(cmp_runs, num: int, oversample: int):
     """Global splitter tuples for a ``num``-way partition of k sorted runs:
-    evenly spaced per-run quantile samples of the compare lanes, pooled and
-    lex-sorted host-side (uint32 compare lanes — a few k*oversample rows),
-    then ``num - 1`` evenly spaced picks. The splitters only steer *balance*;
-    correctness never depends on them because the per-run boundaries are
-    exact searchsorted positions."""
-    samples = [[] for _ in cmp_runs[0]]
-    for cmp_r in cmp_runs:
-        n_r = int(cmp_r[0].shape[0])
-        if n_r == 0:
-            continue
-        pos = np.minimum(np.arange(oversample) * max(1, n_r // oversample),
-                         n_r - 1)
-        for i, lane in enumerate(cmp_r):
-            samples[i].append(np.asarray(lane)[pos])
-    pooled = [np.concatenate(s) for s in samples]
+    evenly spaced per-run quantile samples of the compare lanes, read to
+    the host in one transfer, pooled and lex-sorted there (a few
+    k*oversample rows), then ``num - 1`` evenly spaced picks, as NumPy
+    lanes. The splitters only steer *balance*; correctness never depends
+    on them because the per-run boundaries are exact searchsorted
+    positions."""
+    samples = jax.device_get([_run_samples(tuple(c), oversample)
+                              for c in cmp_runs if c[0].shape[0]])
+    pooled = [np.concatenate(s) for s in zip(*samples)]
     order = np.lexsort(tuple(reversed(pooled)))
-    pooled = [p[order] for p in pooled]
-    take = [(d + 1) * len(order) // num for d in range(num - 1)]
-    return [jnp.asarray(p[take]) for p in pooled]
+    take = order[[(d + 1) * len(order) // num for d in range(num - 1)]]
+    return tuple(p[take] for p in pooled)
+
+
+@jax.jit
+def _run_bounds(splitters, cmp_lanes):
+    """One sorted run's destination boundaries, (num + 1,) int32: 0, each
+    splitter's ``side='right'`` position, the run's length."""
+    pos = lex_searchsorted(list(cmp_lanes), list(splitters), side="right")
+    return jnp.concatenate([
+        jnp.zeros((1,), jnp.int32), pos.astype(jnp.int32),
+        jnp.full((1,), cmp_lanes[0].shape[0], jnp.int32)])
+
+
+@functools.partial(jax.jit, static_argnames=("num", "slot"))
+def _split_slots(bnd, lanes, *, num: int, slot: int):
+    """One sorted run cut at its boundaries ``bnd`` into ``num`` slots of
+    ``slot`` rows, ``out[d][i]`` lane i of slot d: rows ``bnd[d]:bnd[d +
+    1]`` at the slot's head, then padding (the next piece's rows, or zeros
+    past the run), which the combine masks by count."""
+    padded = [jnp.concatenate([x, jnp.zeros((slot,), x.dtype)])
+              for x in lanes]
+    return tuple(tuple(lax.dynamic_slice(x, (bnd[d],), (slot,))
+                       for x in padded) for d in range(num))
+
+
+@functools.partial(jax.jit, static_argnames=("n",))
+def _gather_blocks(offsets, blocks, *, n: int):
+    """The destinations' merged blocks end to end on one device, as
+    ``(lengths, keys)`` of ``n`` rows: block d is written at
+    ``offsets[d]``, in order, so the padding after its real rows is
+    overwritten by block d + 1 or lies past ``n``."""
+    size = n + max(b[0].shape[0] for b in blocks)
+    out = []
+    for lane in zip(*blocks):
+        buf = jnp.zeros((size,), lane[0].dtype)
+        for d, x in enumerate(lane):
+            buf = lax.dynamic_update_slice(buf, x, (offsets[d],))
+        out.append(buf[:n])
+    return out[0], jnp.stack(out[1:], axis=1)
 
 
 def distributed_chunked_sort_lex(keys, mesh=None, axis: str = "data",
@@ -686,11 +740,18 @@ def distributed_chunked_sort_lex(keys, mesh=None, axis: str = "data",
          ``lex_searchsorted`` positions over its packed compare lanes, so
          destination d receives precisely its key range as k contiguous
          sorted sub-runs — counts derive from the boundaries, never from
-         sentinel comparisons, and nothing can be silently lost.
+         sentinel comparisons, and nothing can be silently lost. Each
+         (run, destination) piece travels in a slot of fixed rows
+         (:func:`_slot_rows`, from n and the device count), padded past
+         its count; a piece too large for it doubles the slot (new
+         shapes, so new programs) and is never cut.
       3. **streaming combine** (stage ``'streaming_combine'`` inside
          ``pipeline.merge.merge_runs``): each destination merges its k
-         sub-runs in ONE k-way pass (``kernels/kway_kernel.py``); the
-         concatenation of destinations in order is the global sort.
+         slots in ONE k-way pass (``kernels/kway_kernel.py``), masked by
+         their counts; the destinations' blocks written end to end at
+         offsets from the counts are the global sort. Every shape follows
+         from n and the device count, so a new input of the same size
+         compiles nothing.
 
     ``keys``: packed (n, lanes) uint32 words, host or device. Devices come
     from ``devices`` (explicit list), else ``mesh``'s flat device order,
@@ -715,10 +776,11 @@ def distributed_chunked_sort_lex(keys, mesh=None, axis: str = "data",
     ``validate != 'off'`` the ``check_sharded`` gate proves cross-shard
     boundary ordering + count/histogram(/digest) conservation from
     manifests alone, no rescan. ``gather`` controls the result form:
-    ``True`` (default without a shard store) concatenates onto the default
-    device and returns a ``SortedRun``; ``False`` (default *with* a shard
-    store) skips the gather entirely — for results that don't fit the home
-    device either — and returns the ``pipeline.shards.ShardedRun`` handle.
+    ``True`` (default without a shard store) writes the destinations onto
+    the default device and returns a ``SortedRun``; ``False`` (default
+    *with* a shard store) skips the gather entirely — for results that
+    don't fit the home device either — and returns the
+    ``pipeline.shards.ShardedRun`` handle.
 
     When the ``supervisor`` carries a ``SpeculationPolicy``, each
     destination combine runs through ``run_speculative`` — a straggling
@@ -728,12 +790,7 @@ def distributed_chunked_sort_lex(keys, mesh=None, axis: str = "data",
     Returns the globally sorted :class:`~repro.pipeline.ingest.SortedRun`,
     or a :class:`~repro.pipeline.shards.ShardedRun` when ``gather=False``.
     """
-    from ..pipeline.ingest import SortedRun, _ingest_chunk
-    from ..pipeline.merge import merge_runs
-    from ..pipeline.validate import (ValidationError, check_chunked,
-                                     check_lanes_sorted, check_run,
-                                     check_sharded, multiset_digest)
-    from ..runtime.failure import CapacityOverflow
+    from ..pipeline.ingest import SortedRun
     if on_overflow not in ("raise", "retry", "clip"):
         raise ValueError(f"unknown on_overflow policy {on_overflow!r}")
     if validate not in ("off", "cheap", "full"):
@@ -743,7 +800,6 @@ def distributed_chunked_sort_lex(keys, mesh=None, axis: str = "data",
     if not gather and shard_store is None:
         raise ValueError("gather=False requires a shard_store to spill to")
     devs = _chunk_devices(mesh, axis, devices)
-    num = len(devs)
     if not isinstance(keys, jax.Array):
         keys = np.asarray(keys, dtype=np.uint32)
     n = int(keys.shape[0])
@@ -753,7 +809,30 @@ def distributed_chunked_sort_lex(keys, mesh=None, axis: str = "data",
             return ShardedRun(store=shard_store, manifests=())
         return SortedRun(lengths=jnp.zeros((0,), jnp.int32),
                          keys=jnp.zeros(keys.shape, jnp.uint32))
-    b = -(-n // num)
+    b = -(-n // len(devs))
+    with span("job", rows=n, chunks=-(-n // b)):
+        return _mesh_sort(keys, devs, b, algorithm=algorithm,
+                          capacity=capacity, store=store,
+                          supervisor=supervisor, validate=validate,
+                          on_overflow=on_overflow, merge_engine=merge_engine,
+                          oversample=oversample, shard_store=shard_store,
+                          gather=gather)
+
+
+def _mesh_sort(keys, devs, b, *, algorithm, capacity, store, supervisor,
+               validate, on_overflow, merge_engine, oversample, shard_store,
+               gather):
+    """The body of :func:`distributed_chunked_sort_lex` for non-empty
+    ``keys`` in chunks of ``b`` rows, one per device of ``devs``."""
+    from ..checkpoint.manager import CorruptSnapshotError
+    from ..pipeline.ingest import SortedRun, _ingest_chunk, _run_from_arrays
+    from ..pipeline.manifest import RunManifest
+    from ..pipeline.merge import merge_runs
+    from ..pipeline.validate import (ValidationError, check_chunked,
+                                     check_lanes_sorted, check_run,
+                                     check_sharded, multiset_digest)
+    from ..runtime.failure import CapacityOverflow
+    num, n = len(devs), int(keys.shape[0])
 
     # 1. chunk-per-device ingest (resume/manifests/overflow via the
     # pipeline's own chunk stage)
@@ -769,50 +848,49 @@ def distributed_chunked_sort_lex(keys, mesh=None, axis: str = "data",
 
     lanes_rs = [r.lanes() for r in runs]
     cmp_rs = [r.cmp_lanes() for r in runs]
+    n_cmp, k = len(cmp_rs[0]), len(runs)
+    row_bytes = np.asarray([sum(x.dtype.itemsize for x in lanes + tuple(c))
+                            for lanes, c in zip(lanes_rs, cmp_rs)])
+    slot = _slot_rows(n, num)
 
-    # 2. exact-count exchange of whole sorted sub-runs
+    # 2. exact-count exchange of whole sorted sub-runs, in fixed slots
     def exchange(oversample):
-        if num == 1 or len(runs) == 1:
-            bnds = [jnp.asarray([0, int(r[0].shape[0])] + [int(
-                r[0].shape[0])] * (num - 1), jnp.int32) for r in lanes_rs]
+        nonlocal slot
+        if num == 1 or k == 1:
+            bnds = [np.asarray([0] + [int(r[0].shape[0])] * num, np.int32)
+                    for r in lanes_rs]
+            counts = np.diff(np.stack(bnds), axis=1)
         else:
             with span("sync", what="splitter_samples"):
                 splitters = _run_splitters(cmp_rs, num, oversample)
-            bnds = []
-            for cmp_r, r in zip(cmp_rs, lanes_rs):
-                pos = lex_searchsorted(cmp_r, splitters, side="right")
-                n_r = jnp.asarray([int(r[0].shape[0])], jnp.int32)
-                bnds.append(jnp.concatenate(
-                    [jnp.zeros((1,), jnp.int32),
-                     pos.astype(jnp.int32), n_r]))
-        with span("sync", what="run_boundaries"):
-            bnds = [[int(x) for x in bnd] for bnd in bnds]
-        # (destination, run, lo, hi) of every non-empty sub-run
-        pieces = [(d, r, bnd[d], bnd[d + 1]) for d in range(num)
-                  for r, bnd in enumerate(bnds) if bnd[d + 1] > bnd[d]]
-        row_bytes = [sum(x.dtype.itemsize for x in lanes + tuple(cmps))
-                     for lanes, cmps in zip(lanes_rs, cmp_rs)]
-        per_dest = [([], []) for _ in range(num)]
-        with span("run_exchange", slices=len(pieces),
-                  bytes=sum((hi - lo) * row_bytes[r]
-                            for _, r, lo, hi in pieces)):
-            for d, r, lo, hi in pieces:
-                dev = devs[d % len(devs)]
-                per_dest[d][0].append(tuple(jax.device_put(x[lo:hi], dev)
-                                            for x in lanes_rs[r]))
-                per_dest[d][1].append(tuple(jax.device_put(c[lo:hi], dev)
-                                            for c in cmp_rs[r]))
-        return per_dest
+            bnds = [_run_bounds(splitters, tuple(c)) for c in cmp_rs]
+            with span("sync", what="run_boundaries"):
+                counts = np.diff(np.stack(jax.device_get(bnds)), axis=1)
+        # counts[r, d]: rows of run r bound for destination d
+        while slot < counts.max():
+            log.warning("run exchange: a piece of %d row(s) outgrows its "
+                        "slot of %d: slot -> %d", counts.max(), slot,
+                        2 * slot)
+            slot *= 2
+        with span("run_exchange", slices=int(np.count_nonzero(counts)),
+                  bytes=int(counts.sum(axis=1) @ row_bytes),
+                  rows=int(counts.sum()), slots=num * k * slot):
+            pieces = [_split_slots(bnd, tuple(c) + tuple(x), num=num,
+                                   slot=slot)
+                      for bnd, c, x in zip(bnds, cmp_rs, lanes_rs)]
+            # destination d's k slots onto device d, in one call
+            per_dest = jax.device_put([[p[d] for p in pieces]
+                                       for d in range(num)], devs)
+        return per_dest, counts
 
     while True:
         if supervisor is not None:
-            per_dest = supervisor.run_stage("run_exchange", exchange,
-                                            oversample)
+            per_dest, counts = supervisor.run_stage("run_exchange",
+                                                    exchange, oversample)
         else:
-            per_dest = exchange(oversample)
-        incoming = [sum(int(s[0].shape[0]) for s in sub) if sub else 0
-                    for sub, _ in per_dest]
-        worst = max(incoming) if incoming else 0
+            per_dest, counts = exchange(oversample)
+        incoming = [int(c) for c in counts.sum(axis=0)]
+        worst = max(incoming)
         if capacity is None or worst <= capacity:
             clipped = False
             break
@@ -836,22 +914,24 @@ def distributed_chunked_sort_lex(keys, mesh=None, axis: str = "data",
     # 3. one streaming k-way combine per destination — each output spilled
     # as an atomic shard (when a shard_store is given) the moment it lands,
     # so a kill between destinations loses only the in-flight one
-    from ..checkpoint.manager import CorruptSnapshotError
-    from ..pipeline.ingest import _run_from_arrays
-    from ..pipeline.manifest import RunManifest
-    arity = len(lanes_rs[0])
     speculative = (supervisor is not None
                    and getattr(supervisor, "speculation", None) is not None)
     merged_dests = []        # (gather path) per-destination lane tuples
+    kept = []                # their real rows, at the head of each
     shard_manifests = []     # (spill path) destination-ordered manifests
-    for d, (sub_lanes, sub_cmps) in enumerate(per_dest):
+    for d, pieces in enumerate(per_dest):
+        sub_cmps = [p[:n_cmp] for p in pieces]
+        sub_lanes = [p[n_cmp:] for p in pieces]
+        cnt = counts[:, d].astype(np.int32)
+        keep = min(incoming[d], capacity) if clipped else incoming[d]
         # expected shard identity from the exchange alone: incoming count +
         # summed sub-run key digest (additive, so the merged output's digest
         # equals the sum — no merge needed to know what "done" looks like)
         want_digest = None
         if shard_store is not None:
-            want_digest = sum(multiset_digest(s[1:]) for s in sub_lanes) \
-                % (1 << 64)
+            want_digest = sum(
+                multiset_digest([np.asarray(x)[:c] for x in s[1:]])
+                for s, c in zip(sub_lanes, cnt)) % (1 << 64)
 
         merged = None
         if shard_store is not None:
@@ -884,36 +964,34 @@ def distributed_chunked_sort_lex(keys, mesh=None, axis: str = "data",
                             "shard) — recomputing", d)
 
         if merged is None:
-            if not sub_lanes:
-                merged = (jnp.zeros((0,), jnp.int32),
-                          *(jnp.zeros((0,), jnp.uint32)
-                            for _ in range(arity - 1)))
-            elif speculative:
+            if speculative:
                 # the backup replica re-runs the same pure combine; the
                 # inner merge skips its own stage probe so the speculative
                 # wrapper owns the injector/retry bookkeeping
                 merged = supervisor.run_speculative(
                     "streaming_combine",
-                    lambda sl=sub_lanes, sc=sub_cmps: merge_runs(
+                    lambda sl=sub_lanes, sc=sub_cmps, c=cnt: merge_runs(
                         sl, engine=merge_engine, cmp_runs=sc,
-                        supervisor=None),
+                        supervisor=None, counts=c),
                     digest_of=lambda lanes: multiset_digest(list(lanes)))
             else:
                 merged = merge_runs(sub_lanes, engine=merge_engine,
-                                    cmp_runs=sub_cmps, supervisor=supervisor)
+                                    cmp_runs=sub_cmps, supervisor=supervisor,
+                                    counts=cnt)
             log.info("destination %d: %d row(s) merged on %s", d,
                      incoming[d], sorted(merged[0].devices(), key=str))
-            if clipped and incoming[d] > capacity:
+            if keep < incoming[d]:
                 log.warning("run exchange overflow: destination %d clipped "
                             "%d element(s) past capacity %d", d,
-                            incoming[d] - capacity, capacity)
-                merged = tuple(x[:capacity] for x in merged)
+                            incoming[d] - keep, capacity)
             if shard_store is not None:
+                merged = tuple(x[:keep] for x in merged)
                 run_d = SortedRun.from_lanes(merged)
                 man_d = RunManifest.from_run(run_d, d)
                 shard_store.put(man_d, run_d)
                 shard_manifests.append(man_d)
         merged_dests.append(merged)
+        kept.append(keep)
 
     if shard_store is not None and validate != "off":
         if clipped:
@@ -935,20 +1013,22 @@ def distributed_chunked_sort_lex(keys, mesh=None, axis: str = "data",
         return ShardedRun(store=shard_store,
                           manifests=tuple(shard_manifests))
 
-    # destinations live on their own devices; the host-facing result gathers
-    # onto the default device (committed arrays never concatenate across)
-    home = jax.devices()[0]
-    occupied = [m for m in merged_dests if int(m[0].shape[0])]
-    out = tuple(jnp.concatenate([jax.device_put(m[i], home)
-                                 for m in occupied])
-                for i in range(arity)) if occupied else tuple(
-        jnp.zeros((0,), jnp.int32 if i == 0 else jnp.uint32)
-        for i in range(arity))
-    result = SortedRun.from_lanes(out)
+    # destinations live on their own devices; the host-facing result is
+    # written onto the default device (committed arrays never concatenate
+    # across devices), each block at its offset in one program
+    total = sum(kept)
+    with span("gather", rows=total):
+        offsets = np.cumsum([0] + kept[:-1]).astype(np.int32)
+        lengths, out_keys = _gather_blocks(
+            offsets, jax.device_put(merged_dests, jax.devices()[0]), n=n)
+        if total < n:
+            lengths, out_keys = lengths[:total], out_keys[:total]
+    result = SortedRun(lengths=lengths, keys=out_keys)
 
     if validate != "off":
         if clipped:
-            check_lanes_sorted(out, what="distributed_chunked output")
+            check_lanes_sorted(result.lanes(),
+                               what="distributed_chunked output")
         else:
             check_chunked(runs, manifests, result, mode=validate)
     return result

@@ -83,11 +83,16 @@ __all__ = ["DEFAULT_KWAY_BLOCK", "kway_ranks", "merge_runs_kway_take",
 DEFAULT_KWAY_BLOCK = 256
 
 
-def _merge_perm(cmp_runs):
+def _merge_perm(cmp_runs, counts=None):
     """Source index, in the concatenation of the sorted runs ``cmp_runs``,
     of every slot of their k-way merge, ties broken by run index then in-run
     index (the k-way tie protocol), as a bitonic merge network in plain jnp:
     no gather and no sort.
+
+    ``counts`` (k,) int32, where given, holds each run's real rows; the rows
+    past them are padding, which takes the all-ones order bits and a source
+    index past ``total``, so it follows every real row (one with all-ones
+    bits too) in run order, and maps back to its own index.
 
     Each run pads to ``P`` (a power of two >= 128) with the all-ones order
     bits, the run count to a power of two ``K`` with all-pad runs, and every
@@ -114,12 +119,23 @@ def _merge_perm(cmp_runs):
         return jnp.concatenate(blocks).reshape(rows, LANE_TILE)
 
     top = jnp.uint32(0xFFFFFFFF)  # the largest order bits
-    lanes = [layout([to_order_bits(c[i]) for c in cmp_runs], top)
-             for i in range(nc)]
+    pads = None if counts is None else [
+        jnp.arange(n, dtype=jnp.int32) >= counts[r] for r, n in enumerate(ns)]
+
+    def bits(i):
+        xs = [to_order_bits(c[i]) for c in cmp_runs]
+        return xs if pads is None else [jnp.where(p, top, x)
+                                        for p, x in zip(pads, xs)]
+
+    lanes = [layout(bits(i), top) for i in range(nc)]
     src = jnp.arange(total, dtype=jnp.int32)
     bases = [sum(ns[:r]) for r in range(k)]
-    lanes.append(layout([src[b:b + n] for b, n in zip(bases, ns)],
-                        jnp.int32(total)))
+    srcs = [src[b:b + n] for b, n in zip(bases, ns)]
+    fill = total
+    if pads is not None:
+        srcs = [jnp.where(p, s + total, s) for p, s in zip(pads, srcs)]
+        fill = 2 * total
+    lanes.append(layout(srcs, jnp.int32(fill)))
     idx = (lax.broadcasted_iota(jnp.int32, (rows, LANE_TILE), 0) * LANE_TILE
            + lax.broadcasted_iota(jnp.int32, (rows, LANE_TILE), 1))
     size = 2 * P
@@ -136,10 +152,12 @@ def _merge_perm(cmp_runs):
             lanes = [jnp.where(take, y, x) for x, y in zip(lanes, part)]
             d //= 2
         size *= 2
-    return lanes[-1].reshape(-1)[:total]
+    perm = lanes[-1].reshape(-1)[:total]
+    return perm if pads is None else jnp.where(perm >= total, perm - total,
+                                                perm)
 
 
-def kway_ranks(cmp_runs):
+def kway_ranks(cmp_runs, counts=None):
     """Merge-path rank of every element of every sorted run: a list of int32
     arrays (one per run) that together form a permutation of ``[0, total)``.
 
@@ -150,15 +168,19 @@ def kway_ranks(cmp_runs):
     one scatter. A tournament of pairwise binary-search rounds gives the
     same permutation, but on a TPU v5e its ~2,400 dependent small gathers
     took 443 ms a job at 7 x 32768 + 624 rows, 63% of the device time of
-    the whole sort; the merge network has no gather at all."""
+    the whole sort; the merge network has no gather at all.
+
+    ``counts``: as :func:`_merge_perm`'s; the real rows then take the ranks
+    ``[0, sum(counts))``, and each run's padding ranks above them, in
+    order."""
     cmp_runs = [tuple(c) for c in cmp_runs]
     ns = [c[0].shape[0] for c in cmp_runs]
     total = sum(ns)
     if len(cmp_runs) == 1:
         return [jnp.arange(total, dtype=jnp.int32)]
     iota = jnp.arange(total, dtype=jnp.int32)
-    ranks_flat = jnp.zeros((total,), jnp.int32).at[_merge_perm(cmp_runs)].set(
-        iota, unique_indices=True)
+    ranks_flat = jnp.zeros((total,), jnp.int32).at[
+        _merge_perm(cmp_runs, counts)].set(iota, unique_indices=True)
     bases = [sum(ns[:r]) for r in range(len(ns))]
     return [ranks_flat[b:b + n_r] for b, n_r in zip(bases, ns)]
 
@@ -169,7 +191,7 @@ def _cmp_runs(runs, n_cmp, max_values):
     return [tuple(r[:n_cmp]) for r in runs]
 
 
-def merge_runs_kway_take(runs, n_cmp=None, max_values=None):
+def merge_runs_kway_take(runs, n_cmp=None, max_values=None, counts=None):
     """jnp k-way merge: ONE fused key sort + ONE gather per lane (a single
     data pass; the tournament re-gathers every lane log2(k) times).
 
@@ -184,15 +206,23 @@ def merge_runs_kway_take(runs, n_cmp=None, max_values=None):
     compiles in seconds on a TPU where this sort would take minutes at its
     sizes; off-TPU, where per-op dispatch dominates, one fused sort is the
     cheaper program. Traceable; runs are sequences of equal-arity lane
-    tuples, any lengths."""
+    tuples, any lengths.
+
+    ``counts`` (k,) int32, where given, holds each run's real rows: a
+    leading padding flag keeps the rows past them behind every real row,
+    so the merge's first ``sum(counts)`` rows are the real ones."""
     runs = [list(r) for r in runs]
     cmp_runs = _cmp_runs(runs, n_cmp, max_values)
     nc = len(cmp_runs[0])
     total = sum(r[0].shape[0] for r in runs)
     keys = tuple(to_order_bits(concat_lanes([c[i] for c in cmp_runs]))
                  for i in range(nc))
+    if counts is not None:
+        keys = (concat_lanes([
+            (jnp.arange(r[0].shape[0], dtype=jnp.int32) >= counts[i])
+            .astype(jnp.uint32) for i, r in enumerate(runs)]),) + keys
     src = jnp.arange(total, dtype=jnp.int32)
-    perm = lax.sort(keys + (src,), num_keys=nc, is_stable=True)[-1]
+    perm = lax.sort(keys + (src,), num_keys=len(keys), is_stable=True)[-1]
     return tuple(concat_lanes([r[i] for r in runs])[perm]
                  for i in range(len(runs[0])))
 
@@ -307,8 +337,8 @@ def kway_kernel_call(starts, *flat, nblocks, block, interpret=False):
 @functools.partial(jax.jit, static_argnames=("n_arr", "n_runs", "n_cmp",
                                              "max_values", "block",
                                              "interpret"))
-def _kway_merge_jit(*arrs, n_arr, n_runs, n_cmp, max_values, block,
-                    interpret):
+def _kway_merge_jit(*arrs, counts=None, n_arr, n_runs, n_cmp, max_values,
+                    block, interpret):
     runs = [list(arrs[r * n_arr:(r + 1) * n_arr]) for r in range(n_runs)]
     ns = [r[0].shape[0] for r in runs]
     total = sum(ns)
@@ -316,7 +346,7 @@ def _kway_merge_jit(*arrs, n_arr, n_runs, n_cmp, max_values, block,
 
     # one named scope per stage: the device trace's ops carry these names
     with jax.named_scope("kway_ranks"):
-        ranks = kway_ranks(_cmp_runs(runs, n_cmp, max_values))
+        ranks = kway_ranks(_cmp_runs(runs, n_cmp, max_values), counts)
     # flat layout: run r's lane at [base_r, base_r + ns[r]), then
     # `block + 128` sentinel fill slots — every segment DMA reads a full
     # in-bounds window (runmerge_kernel.pad_run).
@@ -330,6 +360,11 @@ def _kway_merge_jit(*arrs, n_arr, n_runs, n_cmp, max_values, block,
     # SMEM, outgrows it past ~2^22 output elements).
     with jax.named_scope("kway_starts"):
         bounds = jnp.arange(nblocks + 1, dtype=jnp.int32) * block
+        if counts is not None:
+            # no block reaches past the real rows, so padding, which ranks
+            # after them, never enters the kernel: the blocks beyond are
+            # sentinel fill
+            bounds = jnp.minimum(bounds, jnp.sum(counts))
         starts = jnp.stack([
             jnp.int32(bases[r]) + jnp.searchsorted(
                 ranks[r], bounds, side="left").astype(jnp.int32)
@@ -346,7 +381,7 @@ def _kway_merge_jit(*arrs, n_arr, n_runs, n_cmp, max_values, block,
 
 def merge_runs_kway_pallas(runs, n_cmp=None, max_values=None,
                            block: int | None = None,
-                           interpret: bool = False):
+                           interpret: bool = False, counts=None):
     """Merge k sorted lex-tuple runs (sequences of equal-arity tuples of
     parallel 1-D arrays, any lengths) in ONE kernel launch.
 
@@ -355,7 +390,11 @@ def merge_runs_kway_pallas(runs, n_cmp=None, max_values=None,
     bounds for that packing (hashable tuple). ``block`` must be a power of
     two >= 128. Empty runs drop host-side (static shapes); k == 1 returns
     the run as-is. VMEM holds 2*k segments per lane — practical k is a few
-    dozen; past that, chunk the combine."""
+    dozen; past that, chunk the combine.
+
+    ``counts``: each run's real rows (k,), as runs of a fixed shape whose
+    tails are padding; the merge then holds ``sum(counts)`` real rows first
+    and sentinel fill after them, and no run is dropped for its shape."""
     runs = [tuple(r) for r in runs]
     if max_values is not None:
         max_values = tuple(max_values)  # static under jit: must be hashable
@@ -366,13 +405,15 @@ def merge_runs_kway_pallas(runs, n_cmp=None, max_values=None,
     block = DEFAULT_KWAY_BLOCK if block is None else block
     if block < 128 or block & (block - 1):
         raise ValueError("block must be a power of two >= 128")
-    nonempty = [r for r in runs if r[0].shape[0]]
+    nonempty = runs if counts is not None else [r for r in runs
+                                                if r[0].shape[0]]
     if not nonempty:
         return runs[0]
     if len(nonempty) == 1:
         return nonempty[0]
     with span("dispatch", program="_kway_merge_jit"):
         return _kway_merge_jit(*[x for r in nonempty for x in r],
+                               counts=counts,
                                n_arr=len(runs[0]), n_runs=len(nonempty),
                                n_cmp=n_cmp, max_values=max_values,
                                block=block, interpret=interpret)

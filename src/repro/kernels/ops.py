@@ -466,14 +466,15 @@ def choose_kway_engine(total: int, engine: str = "auto") -> str:
 
 @functools.partial(jax.jit, static_argnames=("n_arr", "n_runs", "n_cmp",
                                              "max_values"))
-def _kway_take_jit(*arrs, n_arr, n_runs, n_cmp, max_values):
+def _kway_take_jit(*arrs, counts=None, n_arr, n_runs, n_cmp, max_values):
     runs = [list(arrs[r * n_arr:(r + 1) * n_arr]) for r in range(n_runs)]
-    return merge_runs_kway_take(runs, n_cmp=n_cmp, max_values=max_values)
+    return merge_runs_kway_take(runs, n_cmp=n_cmp, max_values=max_values,
+                                counts=counts)
 
 
 def merge_runs_lex(runs, engine: str = "auto", n_cmp: int | None = None,
                    max_values=None, block_size: int | None = None,
-                   interpret: bool | None = None):
+                   interpret: bool | None = None, counts=None):
     """Merge k *sorted* lex-tuple runs into one sorted run in a SINGLE pass
     — the streaming replacement for the pipeline tournament's ceil(log2 k)
     pairwise rounds (each of which re-reads and re-writes all the data).
@@ -484,7 +485,11 @@ def merge_runs_lex(runs, engine: str = "auto", n_cmp: int | None = None,
     streaming Pallas kernel, ``kernels/kway_kernel.py``), or 'auto'
     (:func:`choose_kway_engine`). ``n_cmp``/``max_values`` follow
     :func:`merge_sorted_lex`. Output is bit-identical to the tournament and
-    the NumPy lexsort oracle across engines."""
+    the NumPy lexsort oracle across engines.
+
+    ``counts``: for runs of a fixed shape whose tails are padding, each
+    run's real rows (k,) int32; the merge then holds their ``sum(counts)``
+    rows first, and no run is dropped for its shape."""
     runs = [tuple(r) for r in runs]
     if max_values is not None:
         max_values = tuple(max_values)  # static under jit: must be hashable
@@ -492,7 +497,8 @@ def merge_runs_lex(runs, engine: str = "auto", n_cmp: int | None = None,
         raise ValueError("runs must share a non-zero lane arity")
     if any(x.ndim != 1 for r in runs for x in r):
         raise ValueError("runs must be tuples of 1-D arrays")
-    nonempty = [r for r in runs if r[0].shape[0]]
+    nonempty = runs if counts is not None else [r for r in runs
+                                                if r[0].shape[0]]
     if not nonempty:
         return runs[0]
     if len(nonempty) == 1:
@@ -503,9 +509,11 @@ def merge_runs_lex(runs, engine: str = "auto", n_cmp: int | None = None,
         return merge_runs_kway_pallas(nonempty, n_cmp=n_cmp,
                                       max_values=max_values,
                                       block=block_size,
-                                      interpret=_auto_interpret(interpret))
+                                      interpret=_auto_interpret(interpret),
+                                      counts=counts)
     with span("dispatch", program="_kway_take_jit"):
         return _kway_take_jit(*[x for r in nonempty for x in r],
+                              counts=counts,
                               n_arr=len(runs[0]), n_runs=len(nonempty),
                               n_cmp=n_cmp, max_values=max_values)
 

@@ -45,7 +45,7 @@ def merge_two(a_lanes, b_lanes, engine: str = "auto", max_values=None):
 def merge_runs(runs, engine: str = "auto", max_values=None, cmp_runs=None,
                manifests=None, supervisor=None,
                interpret: bool | None = None,
-               block_size: int | None = None):
+               block_size: int | None = None, counts=None):
     """k-way merge of sorted runs into one. ``runs``: list of sorted
     lex-tuple runs of equal arity; an empty list returns ``()`` and a single
     run is returned as-is — both without touching the device.
@@ -72,9 +72,16 @@ def merge_runs(runs, engine: str = "auto", max_values=None, cmp_runs=None,
     instead of merging short. ``supervisor``: optional
     ``runtime.SortSupervisor`` — combine stages are pure functions of their
     input runs, so a failed stage simply re-executes. ``interpret`` /
-    ``block_size`` forward to the kernel tiers (``None`` = auto)."""
+    ``block_size`` forward to the kernel tiers (``None`` = auto).
+
+    ``counts``: runs of a fixed shape (the mesh exchange's slots) hold
+    ``counts[r]`` real rows and then padding; the merged run then holds
+    ``sum(counts)`` real rows first and padding after them, so a new input
+    of the same shapes compiles nothing. The k-way engines only."""
     if engine not in _ENGINES:
         raise ValueError(f"unknown merge_runs engine {engine!r}")
+    if counts is not None and engine == "tournament":
+        raise ValueError("counts need a k-way engine, not 'tournament'")
     runs = [tuple(r) for r in runs]
     if manifests is not None:
         from .validate import ValidationError
@@ -96,7 +103,8 @@ def merge_runs(runs, engine: str = "auto", max_values=None, cmp_runs=None,
         cmp_runs = [packed_cmp_lanes(list(r), max_values) for r in runs]
     ext = [tuple(c) + r for c, r in zip(cmp_runs, runs)]
     n_cmp = len(ext[0]) - arity
-    rows = sum(int(r[0].shape[0]) for r in runs)
+    rows = (int(sum(counts)) if counts is not None
+            else sum(int(r[0].shape[0]) for r in runs))
 
     if engine != "tournament":
         ops_engine = "kernel" if engine == "kway_kernel" else "auto"
@@ -104,7 +112,7 @@ def merge_runs(runs, engine: str = "auto", max_values=None, cmp_runs=None,
         def combine(ext_rs):
             return merge_runs_lex(ext_rs, engine=ops_engine, n_cmp=n_cmp,
                                   block_size=block_size,
-                                  interpret=interpret)
+                                  interpret=interpret, counts=counts)
 
         with span("streaming_combine", runs=len(ext), rows=rows):
             if supervisor is None:

@@ -24,7 +24,8 @@ __all__ = ["SPANS", "span"]
 # every span the program emits, without the ``sort.`` prefix; the names of
 # the supervisor's stages where one exists
 SPANS = (
-    "job",                 # one call of a chunked entry point: rows, chunks
+    "job",                 # one call of a chunked entry point, the mesh
+                           # one included: rows, chunks
     "stage",               # one chunk's host->device copy, on the prefetch
                            # worker thread: chunk, bytes
     "ingest_chunk",        # one chunk's fused sort: chunk, rows, capacity
@@ -33,7 +34,9 @@ SPANS = (
     "streaming_combine",   # the one-pass k-way combine: runs, rows
     "merge_round",         # one round of the pairwise tournament: runs, rows
     "run_exchange",        # the mesh exchange's slices and copies: slices,
-                           # bytes
+                           # bytes, rows (real), slots (padded rows handed
+                           # to the combines)
+    "gather",              # the mesh result written onto one device: rows
 )
 
 

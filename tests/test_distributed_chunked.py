@@ -23,9 +23,9 @@ from repro.core.packing import pack_words, unpack_words
 from repro.pipeline import chunked_sort_packed
 
 
-def _run_multidev(script, timeout=600):
+def _run_multidev(script, timeout=600, devices=8):
     env = dict(os.environ)
-    env["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
+    env["XLA_FLAGS"] = f"--xla_force_host_platform_device_count={devices}"
     env["PYTHONPATH"] = os.path.join(os.path.dirname(__file__), "..", "src")
     env["JAX_PLATFORMS"] = "cpu"
     out = subprocess.run([sys.executable, "-c", script],
@@ -304,3 +304,140 @@ assert "speculation_confirmed" in actions, actions
 print("SPECULATE_OK")
 """)
     assert "SPECULATE_OK" in out
+
+
+# ---------------------------------------------------------------------------
+# fixed shapes: every program of the mesh path follows from n and the device
+# count alone (4 fake devices, as the four chips of one host)
+# ---------------------------------------------------------------------------
+
+_MESH4 = """
+import logging, sys
+import numpy as np, jax
+sys.path.insert(0, {bench!r})
+from compile_log import CompileLog
+from repro.core.distributed import distributed_chunked_sort_lex
+from repro.core.packing import pack_words, unpack_words
+
+assert len(jax.devices()) == 4
+
+def words(n, seed, hot=0.0):
+    rng = np.random.default_rng(seed)
+    ws = ["".join(rng.choice(list("abcdefgh"), l))
+          for l in rng.integers(0, 9, n)]
+    return ["abc" if rng.random() < hot else w for w in ws]
+
+def oracle(ws):
+    return sorted(ws, key=lambda w: (len(w.encode()), w.encode()))
+
+def check(run, ws):
+    got = unpack_words(np.asarray(run.keys))
+    assert got == oracle(ws)
+    assert np.asarray(run.lengths).tolist() == [len(w) for w in got]
+"""
+
+
+def _bench_dir():
+    return os.path.join(os.path.dirname(os.path.abspath(__file__)), "..",
+                        "bench")
+
+
+def test_mesh_corpora_of_one_size_share_every_program():
+    """Two corpora of the same n with different keys each come back in
+    exact shortlex order, and the second compiles nothing: the exchange's
+    slots, the combines and the gather take their shapes from n and the
+    device count, never from where the splitters cut the keys."""
+    out = _run_multidev(_MESH4.format(bench=_bench_dir()) + """
+first, second = words(1000, 3), words(1000, 4)
+with CompileLog() as warm:
+    check(distributed_chunked_sort_lex(pack_words(first)), first)
+with CompileLog() as again:
+    check(distributed_chunked_sort_lex(pack_words(second)), second)
+assert warm.compiles() > 0
+assert again.compiles() == 0, str(again)
+print("FIXED_SHAPES_OK")
+""", devices=4)
+    assert "FIXED_SHAPES_OK" in out
+
+
+def test_mesh_hot_key_past_its_slot_keeps_every_row():
+    """A hot key pushes one destination's pieces past the default slot (128
+    rows for chunks of 256 on 4 devices): the slot doubles, with a warning,
+    and the default call comes back bit-identical. Against a destination
+    capacity below the hot destination, 'raise' raises, 'retry' comes back
+    bit-identical and 'clip' keeps each destination's capacity smallest
+    rows, in order."""
+    out = _run_multidev(_MESH4.format(bench=_bench_dir()) + """
+from repro.core.distributed import _slot_rows
+from repro.runtime import CapacityOverflow
+
+assert _slot_rows(1024, 4) == 128
+hot = words(1024, 5, hot=0.7)
+keys = pack_words(hot)
+grew = []
+handler = logging.Handler()
+handler.emit = lambda record: grew.append(record.getMessage())
+logging.getLogger("repro.core").addHandler(handler)
+run = distributed_chunked_sort_lex(keys)
+check(run, hot)
+assert any("outgrows its slot of 128" in m for m in grew), grew
+
+n_hot = hot.count("abc")
+try:
+    distributed_chunked_sort_lex(keys, capacity=400, on_overflow="raise")
+    raise SystemExit("expected CapacityOverflow")
+except CapacityOverflow as e:
+    assert e.capacity == 400 and e.required >= n_hot
+check(distributed_chunked_sort_lex(keys, capacity=400, on_overflow="retry",
+                                   validate="full"), hot)
+
+clip = distributed_chunked_sort_lex(keys, capacity=400, on_overflow="clip",
+                                    validate="cheap")
+got, want = unpack_words(np.asarray(clip.keys)), oracle(hot)
+# every copy of the hot word lands on one destination, the only one past
+# 400 rows: the output is the oracle less that destination's largest rows,
+# one contiguous block of at least n_hot - 400
+drop = len(want) - len(got)
+assert drop >= n_hot - 400
+i = next((i for i, (a, b) in enumerate(zip(got, want)) if a != b),
+         len(got))
+assert got[:i] == want[:i] and got[i:] == want[i + drop:]
+assert 0 < got.count("abc") <= 400
+print("HOT_KEY_OK")
+""", devices=4)
+    assert "HOT_KEY_OK" in out
+
+
+@pytest.mark.parametrize("engine", ["kway", "kway_kernel"])
+def test_merge_runs_at_a_fixed_shape_equals_the_exact_merge(engine):
+    """One chip's ``merge_runs`` over runs padded to one fixed shape, with
+    their real counts, gives the merge of the exact runs first: padding of
+    any value (here random, below real rows too) never enters, and a real
+    row equal to the all-ones tuple still precedes every padding row."""
+    from repro.pipeline.merge import merge_runs
+    rng = np.random.default_rng(7)
+    top = np.uint32(0xFFFFFFFF)
+    slot, counts = 256, [90, 0, 130, 256]
+    runs = []
+    for c in counts:
+        a = rng.integers(0, 6, (c, 2)).astype(np.uint32)
+        a[rng.random(c) < 0.1] = top          # real rows at the maximum
+        a = a[np.lexsort((a[:, 1], a[:, 0]))]
+        runs.append((a[:, 0], a[:, 1]))
+    padded = [tuple(np.concatenate([x, rng.integers(
+        0, 2**32, slot - len(x), dtype=np.uint32)]) for x in r)
+        for r in runs]
+    exact = merge_runs([tuple(jnp.asarray(x) for x in r) for r in runs
+                        if len(r[0])], engine=engine)
+    fixed = merge_runs([tuple(jnp.asarray(x) for x in r) for r in padded],
+                       engine=engine, counts=np.asarray(counts, np.int32))
+    total = sum(counts)
+    both = np.concatenate([np.stack(r, axis=1) for r in runs])
+    want = both[np.lexsort((both[:, 1], both[:, 0]))]
+    for i in range(2):
+        assert fixed[i].shape == (slot * len(counts),)
+        np.testing.assert_array_equal(np.asarray(fixed[i])[:total],
+                                      np.asarray(exact[i]))
+        np.testing.assert_array_equal(np.asarray(exact[i]), want[:, i])
+    with pytest.raises(ValueError, match="k-way"):
+        merge_runs(padded, engine="tournament", counts=counts)

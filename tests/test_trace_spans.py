@@ -216,7 +216,10 @@ print(json.dumps([[s[0], s[4]] for s in read_spans(d)]))
 """
 
 
-def test_mesh_path_emits_exchange_and_boundary_sync():
+@pytest.fixture(scope="module")
+def mesh_spans():
+    """``(name, attrs)`` of every span of one traced call of the mesh path
+    on 4 fake devices, 400 words, after an untraced call that compiles."""
     env = dict(os.environ,
                XLA_FLAGS="--xla_force_host_platform_device_count=4",
                JAX_PLATFORMS="cpu",
@@ -227,7 +230,11 @@ def test_mesh_path_emits_exchange_and_boundary_sync():
          _MESH.format(tests=os.path.dirname(os.path.abspath(__file__)))],
         capture_output=True, text=True, env=env, timeout=600)
     assert out.returncode == 0, out.stderr[-3000:]
-    spans = json.loads(out.stdout.strip().splitlines()[-1])
+    return json.loads(out.stdout.strip().splitlines()[-1])
+
+
+def test_mesh_path_emits_exchange_and_boundary_sync(mesh_spans):
+    spans = mesh_spans
     (exchange,) = [a for n, a in spans if n == "sort.run_exchange"]
     # 4 runs cut at 3 splitters: at least one slice per destination, at
     # most 4 x 4; each row carries its length and key lanes and its
@@ -239,3 +246,30 @@ def test_mesh_path_emits_exchange_and_boundary_sync():
         "run_boundaries", "splitter_samples"]
     assert sum(n == "sort.ingest_chunk" for n, _ in spans) == 4
     assert sum(n == "sort.streaming_combine" for n, _ in spans) == 4
+
+
+def test_mesh_path_emits_one_job_and_one_gather(mesh_spans):
+    """The mesh call is one ``sort.job`` of its rows and chunks, ends in
+    one ``sort.gather`` of every row, and its exchange hands the combines
+    every row in slots of fixed rows: 4 runs x 4 destinations x 128."""
+    spans = mesh_spans
+    named = {}
+    for n, a in spans:
+        named.setdefault(n, []).append(a)
+    assert named["sort.job"] == [{"rows": 400, "chunks": 4}]
+    assert named["sort.gather"] == [{"rows": 400}]
+    (exchange,) = named["sort.run_exchange"]
+    assert exchange["rows"] == 400
+    assert exchange["slots"] == 4 * 4 * 128 >= exchange["rows"]
+
+
+def test_the_readme_lists_every_span_and_its_attributes():
+    with open(os.path.join(os.path.dirname(__file__), "..",
+                           "README.md")) as f:
+        rows = {m.group(1): m.group(2) for m in re.finditer(
+            r"^\| `sort\.(\w+)` \|.*\| ([^|]*) \|$", f.read(), re.M)}
+    assert set(rows) == set(SPANS)
+    for name, attrs in (("job", "rows chunks"), ("gather", "rows"),
+                        ("run_exchange", "slices bytes rows slots")):
+        assert set(re.findall(r"`(\w+)`", rows[name])) == set(
+            attrs.split()), name
