@@ -156,11 +156,12 @@ def sort_buckets(keys: jax.Array, algorithm: str = "oets",
 def _fused_sort_packed(keys, *, capacity: int, algorithm: str):
     """One jitted program: distribute scatter -> segmented bucket sort ->
     shortlex compaction -> packed rank keys. ``keys`` (n, lanes) uint32 in;
-    out come ``(lengths (B*cap,), sorted (B*cap, lanes), counts (B,),
-    packed)`` with the real words occupying the leading
-    ``min(counts, cap).sum()`` slots in exact shortlex order and sentinel
-    fill beyond (the caller slices). ``packed`` is the tuple of 1-2 uint32
-    rank-key lanes of the compacted shortlex tuples
+    out come ``(lengths (n,), sorted (n, lanes), counts (B,), packed)``:
+    the kept words, ``min(counts, cap).sum()`` of them (all n unless a
+    bucket overflows ``cap``), fill the leading rows in exact shortlex
+    order, and the rows past them hold length 0 and the sentinel (the
+    caller slices). ``packed`` is the tuple of 1-2 uint32 (n,) rank-key
+    lanes of the compacted shortlex tuples
     (``kernels.keypack.pack_shortlex`` — a few bit ops fused into the same
     program), which the run-merge tier ranks on instead of re-packing."""
     from ..kernels.keypack import pack_shortlex
@@ -178,23 +179,26 @@ def _fused_sort_packed(keys, *, capacity: int, algorithm: str):
     with jax.named_scope("bucket_sort"):
         sorted_keys = sort_buckets(buckets, algorithm, counts=counts_c)
     # compaction: bucket b's i-th real word lands at offset[b] + i — the
-    # concatenation-in-length-order of the paper's phase 4, as one scatter
+    # concatenation-in-length-order of the paper's phase 4, as one
+    # contiguous copy a bucket. In increasing length order each whole
+    # sorted block lands at its offset, and the next bucket's head
+    # overwrites its sentinel tail; ``capacity`` rows of headroom keep
+    # every start in range, so no copy is clamped
     with jax.named_scope("compact"):
-        offsets = jnp.cumsum(counts_c) - counts_c
-        slot_in = jnp.arange(capacity, dtype=jnp.int32)
-        valid = slot_in[None, :] < counts_c[:, None]
-        pos = jnp.where(valid, offsets[:, None] + slot_in[None, :],
-                        num_buckets * capacity).reshape(-1)
-        flat_keys = jnp.full((num_buckets * capacity + 1, lanes),
-                             packing.SENTINEL_U32, jnp.uint32
-                             ).at[pos].set(sorted_keys.reshape(-1, lanes))
-        blen = jnp.broadcast_to(
-            jnp.arange(num_buckets, dtype=jnp.int32)[:, None],
-            (num_buckets, capacity)).reshape(-1)
-        flat_lens = jnp.zeros((num_buckets * capacity + 1,), jnp.int32
-                              ).at[pos].set(blen)
-        m = num_buckets * capacity
-        flat_lens, flat_keys = flat_lens[:m], flat_keys[:m]
+        ends = jnp.cumsum(counts_c)
+        offsets = ends - counts_c
+        flat_keys = jnp.full((n + capacity, lanes), packing.SENTINEL_U32,
+                             jnp.uint32)
+        for b in range(num_buckets):
+            flat_keys = jax.lax.dynamic_update_slice(
+                flat_keys, sorted_keys[b], (offsets[b], 0))
+        flat_keys = flat_keys[:n]
+        # a row's length is its bucket: the number of buckets ending at or
+        # before it; 0 past the kept rows
+        row = jnp.arange(n, dtype=jnp.int32)
+        flat_lens = jnp.sum(ends[None, :] <= row[:, None], axis=1,
+                            dtype=jnp.int32)
+        flat_lens = jnp.where(row < ends[-1], flat_lens, 0)
     with jax.named_scope("rank_keys"):
         packed = pack_shortlex(flat_lens, flat_keys)
     return flat_lens, flat_keys, counts, tuple(packed.lanes)
@@ -219,8 +223,10 @@ def sorted_packed(keys, algorithm: str = "pallas",
     ``ValueError``), ``'retry'`` (re-run the fused program at the true
     histogram max — lossless, one extra launch), or ``'clip'`` (drop the
     overflow: the outputs shrink to the surviving element count, with a
-    warning log). The per-chunk producer of the ``repro.pipeline``
-    sorted-run tier."""
+    warning log). The fused program's outputs have n rows, the kept words
+    first and rows of length 0 and the sentinel past them; the host slices
+    them to the kept count. The per-chunk producer of the
+    ``repro.pipeline`` sorted-run tier."""
     from ..runtime.failure import CapacityOverflow
     if on_overflow not in ("raise", "retry", "clip"):
         raise ValueError(f"unknown on_overflow policy {on_overflow!r}")

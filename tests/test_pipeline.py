@@ -205,6 +205,109 @@ def test_sorted_packed_shortlex_and_lengths():
 
 
 # ---------------------------------------------------------------------------
+# the fused program's compaction against a NumPy shortlex reference
+# ---------------------------------------------------------------------------
+
+def _words_of_lengths(lengths, seed):
+    """Words of the given byte lengths over a 3-letter alphabet (so equal
+    words repeat), in a shuffled arrival order."""
+    rng = np.random.default_rng(seed)
+    lengths = rng.permutation(np.asarray(lengths, np.int64))
+    return ["".join(rng.choice(list("abc"), int(l))) for l in lengths]
+
+
+def _shortlex_reference(words, capacity, lanes=4):
+    """NumPy shortlex reference of the fused program's n output rows: each
+    length keeps its first ``capacity`` words in arrival order, those sort
+    by (length, packed lanes), and the rows past them hold length 0 and
+    the sentinel. Returns ``(lengths, keys, (hi, lo), kept)`` with the
+    packed rank keys the top 64 bits of (length, lane0, ..., laneL-1)."""
+    keys = np.asarray(pack_words(words, width=4 * lanes)).astype(np.uint64)
+    lens = np.asarray([len(w.encode()) for w in words], np.int64)
+    seen = {}
+    keep = np.zeros(len(words), bool)
+    for i, l in enumerate(lens):
+        keep[i] = seen.get(l, 0) < capacity
+        seen[l] = seen.get(l, 0) + 1
+    order = np.lexsort([keys[:, l] for l in reversed(range(lanes))]
+                       + [lens])
+    order = order[keep[order]]
+    kept = len(order)
+    out_lens = np.zeros(len(words), np.int32)
+    out_keys = np.full((len(words), lanes), SENTINEL_U32, np.uint32)
+    out_lens[:kept] = lens[order]
+    out_keys[:kept] = keys[order]
+    len_bits = (4 * lanes).bit_length()
+    total = len_bits + 32 * lanes
+    hi = np.zeros(len(words), np.uint32)
+    lo = np.zeros(len(words), np.uint32)
+    for r in range(len(words)):
+        v = int(out_lens[r])
+        for l in range(lanes):
+            v = (v << 32) | int(out_keys[r, l])
+        v >>= max(total - 64, 0)
+        hi[r], lo[r] = v >> 32, v & 0xFFFFFFFF
+    return out_lens, out_keys, (hi, lo), kept
+
+
+# (name, byte length of every word, capacity): the bucket layouts the
+# compaction has to place
+_LAYOUTS = [
+    ("one_length", [5] * 48, 48),
+    ("empty_buckets_between", [0] * 7 + [2] * 20 + [9] * 20 + [13] * 3, 20),
+    ("all_lengths_last_full", [l for l in range(1, 16) for _ in range(3)]
+     + [16] * 24, 24),
+    ("n_is_1", [3], 1),
+    ("capacity_above_n", list(np.arange(60) % 17), 96),
+    ("capacity_below_largest", [4] * 40 + [7] * 12 + [1] * 5, 9),
+]
+
+
+@pytest.mark.parametrize("name,lengths,capacity", _LAYOUTS,
+                         ids=[c[0] for c in _LAYOUTS])
+def test_fused_program_compacts_bucket_layouts(name, lengths, capacity):
+    """The fused program's outputs have n rows; the first
+    ``sum(min(counts, capacity))`` are the kept words in exact shortlex
+    order, the rest length 0 and the sentinel, and lengths, keys and packed
+    rank keys all equal the NumPy reference bit for bit."""
+    words = _words_of_lengths(lengths, seed=len(name))
+    n = len(words)
+    lens, keys, counts, packed = core_bucketing._fused_sort_packed(
+        jnp.asarray(pack_words(words, width=16)), capacity=capacity,
+        algorithm="pallas")
+    want_lens, want_keys, want_packed, kept = _shortlex_reference(
+        words, capacity)
+    assert kept == int(np.minimum(np.asarray(counts), capacity).sum())
+    assert lens.shape == (n,) and keys.shape == (n, 4)
+    assert [p.shape for p in packed] == [(n,), (n,)]
+    np.testing.assert_array_equal(np.asarray(lens), want_lens)
+    np.testing.assert_array_equal(np.asarray(keys), want_keys)
+    for got, want in zip(packed, want_packed):
+        np.testing.assert_array_equal(np.asarray(got), want)
+
+
+@pytest.mark.parametrize("on_overflow", ["clip", "retry"])
+def test_sorted_packed_below_the_largest_bucket(on_overflow):
+    """A capacity below the largest bucket through ``sorted_packed``:
+    'clip' returns the kept words, each length's first ``capacity`` in
+    arrival order, and 'retry' every word, both bit for bit against the
+    NumPy reference."""
+    words = _words_of_lengths([4] * 40 + [7] * 12 + [1] * 5 + [16] * 10,
+                              seed=7)
+    capacity = 9
+    lens, keys, packed = sorted_packed(
+        jnp.asarray(pack_words(words, width=16)), capacity=capacity,
+        return_packed=True, on_overflow=on_overflow)
+    want_lens, want_keys, want_packed, kept = _shortlex_reference(
+        words, capacity if on_overflow == "clip" else len(words))
+    assert kept == (9 + 9 + 5 + 9 if on_overflow == "clip" else len(words))
+    np.testing.assert_array_equal(np.asarray(lens), want_lens[:kept])
+    np.testing.assert_array_equal(np.asarray(keys), want_keys[:kept])
+    for got, want in zip(packed, want_packed):
+        np.testing.assert_array_equal(np.asarray(got), want[:kept])
+
+
+# ---------------------------------------------------------------------------
 # run merge
 # ---------------------------------------------------------------------------
 

@@ -188,6 +188,20 @@ def test_fused_program_carries_its_scopes():
             "rank_keys"} <= _scopes(text, "_fused_sort_packed")
 
 
+def test_fused_program_compacts_without_a_scatter():
+    """The compaction copies each bucket's block to its offset: the lowered
+    program keeps its ``compact`` and ``rank_keys`` scopes, and no scatter
+    sits under ``compact``."""
+    text = _fused_sort_packed.lower(
+        jax.ShapeDtypeStruct((256, 4), jnp.uint32), capacity=256,
+        algorithm="pallas").as_text(debug_info=True)
+    assert {"compact", "rank_keys"} <= _scopes(text, "_fused_sort_packed")
+    compact_ops = set(re.findall(
+        r'"jit\(_fused_sort_packed\)/compact/(?:[^"]*/)?(\w+)"', text))
+    assert "dynamic_update_slice" in compact_ops
+    assert not {op for op in compact_ops if "scatter" in op}
+
+
 def test_kway_program_carries_its_scopes():
     args = [jax.ShapeDtypeStruct((n,), d) for n in (300, 200, 100)
             for d in (jnp.uint32, jnp.int32, jnp.uint32)]
